@@ -1,11 +1,13 @@
 """Distributed engine benchmark: sharded compact vs sharded dense.
 
 Runs the uci-medium-class shape through ``distributed_yinyang`` on a
-multi-device mesh — on CPU boxes the devices are forced with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set below
-BEFORE jax initialises, so this module must be the process entrypoint:
-``python -m benchmarks.distributed_bench``; ``benchmarks/run.py``
-spawns it as a subprocess for exactly that reason).
+mesh over every device of the process. ``benchmarks/run.py`` calls
+:func:`main` in its own process on the devices that exist (at least
+two, e.g. the four chips of a v5e host). The CPU run is its own
+command, ``python -m benchmarks.distributed_bench``: only as the
+process entry point does this module force four CPU devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=4``, set below
+before jax initialises).
 
 Reports, and records under the ``"distributed"`` key of
 ``BENCH_kmeans.json``:
@@ -43,7 +45,7 @@ import numpy as np       # noqa: E402
 
 from repro.configs.kpynq import paper_suite               # noqa: E402
 from repro.core import (distributed_yinyang, engine_fit,  # noqa: E402
-                        kmeans_plusplus)
+                        kmeans_plusplus, make_mesh)
 from repro.data import make_points                        # noqa: E402
 
 
@@ -66,7 +68,7 @@ def run(scale=1.0, dataset="uci-medium", repeats=3):
     pts_np, _, _ = make_points(n, prob.n_dims, prob.k, seed=0)
     pts = jnp.asarray(pts_np)
     init = kmeans_plusplus(jax.random.PRNGKey(1), pts, prob.k)
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh(n_dev)
 
     kw = dict(n_groups=prob.n_groups, max_iters=prob.max_iters,
               tol=prob.tol)
@@ -159,4 +161,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main()

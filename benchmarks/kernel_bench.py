@@ -69,4 +69,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main()

@@ -101,4 +101,6 @@ def main(argv=None, *, scale=None, json_path=None):
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main()

@@ -8,10 +8,12 @@ Prints ``name,us_per_call,derived`` CSV per line, and writes the
 K-means perf record to ``BENCH_kmeans.json`` (per-dataset ``lloyd_ms``,
 ``engine_ms``, ``speedup``, ``work_reduction``, winning ``tuned``
 config + suite means, plus the ``streaming`` and ``distributed``
-subsystem records — the latter measured in a
-``benchmarks.distributed_bench`` subprocess so the forced multi-device
-CPU runtime can initialise) so the perf trajectory is tracked across
-PRs.
+subsystem records — the latter measured in this process on the devices
+that exist, and only when there are at least two; the forced
+multi-device CPU run is ``python -m benchmarks.distributed_bench``) so
+the perf trajectory is tracked across PRs. Every section runs in this
+one process (a chip belongs to one process), and a section that fails
+fails the run.
 
 ``--tune`` refreshes the engine's per-(platform, N, K, D) tuning cache
 (``benchmarks/autotune.py`` -> :mod:`repro.tune`) for the suite's
@@ -373,21 +375,18 @@ def check(args) -> None:
          f"inertia_gap={srow['inertia_gap'] * 100:+.2f}% (limit +5%)")
 
     # perfetto trace artifact: one profiled engine fit, phases annotated
-    try:
-        import jax
-        import jax.numpy as jnp
+    import jax
+    import jax.numpy as jnp
 
-        from repro.core import engine_fit, kmeans_plusplus
-        from repro.data import make_points
-        pts_np, _, _ = make_points(4096, 8, 16, seed=0)
-        pts = jnp.asarray(pts_np)
-        init = kmeans_plusplus(jax.random.PRNGKey(1), pts, 16)
-        _, tdir = profile(engine_fit, pts, init, max_iters=10,
-                          backend="compact", tune="off",
-                          trace_dir="obs_trace", registry=reg)
-        print(f"check: perfetto trace -> {tdir}")
-    except Exception as e:           # the trace is an artifact, not a gate
-        print(f"check: perfetto trace skipped ({e})")
+    from repro.core import engine_fit, kmeans_plusplus
+    from repro.data import make_points
+    pts_np, _, _ = make_points(4096, 8, 16, seed=0)
+    pts = jnp.asarray(pts_np)
+    init = kmeans_plusplus(jax.random.PRNGKey(1), pts, 16)
+    _, tdir = profile(engine_fit, pts, init, max_iters=10,
+                      backend="compact", tune="off",
+                      trace_dir="obs_trace", registry=reg)
+    print(f"check: perfetto trace -> {tdir}")
 
     finish()
 
@@ -447,19 +446,17 @@ def main() -> None:
     print("# === resilience (checkpointed streaming, crash replay) ===",
           flush=True)
     resilience_bench.main(scale=scale, json_path=args.json or None)
-    print("# === distributed engine (forced multi-device CPU) ===",
-          flush=True)
-    # subprocess: the forced device count must be set before jax
-    # initialises, which is long done in THIS process
-    import os
-    import subprocess
-    cmd = [sys.executable, "-m", "benchmarks.distributed_bench",
-           "--scale", str(scale)] + \
-        (["--out", args.json] if args.json else ["--out", ""])
-    r = subprocess.run(cmd, env=dict(os.environ))
-    if r.returncode:
-        print(f"# distributed_bench failed (exit {r.returncode})",
+    import jax
+    n_dev = jax.device_count()
+    if n_dev >= 2:
+        print(f"# === distributed engine ({n_dev} devices) ===",
               flush=True)
+        from . import distributed_bench
+        distributed_bench.main(["--scale", str(scale),
+                                "--out", args.json or ""])
+    else:
+        print("# === distributed engine: not run on 1 device (CPU: "
+              "python -m benchmarks.distributed_bench) ===", flush=True)
     print("# === filter efficiency (multi-level filter rates) ===",
           flush=True)
     filter_efficiency.main()
@@ -473,4 +470,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main()

@@ -279,4 +279,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main()

@@ -101,4 +101,6 @@ def main(scale=1.0, epochs=3, json_path=None):
 
 
 if __name__ == "__main__":
+    from repro.platform import use_compile_cache
+    use_compile_cache()
     main(json_path="BENCH_kmeans.json")

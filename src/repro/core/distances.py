@@ -1,9 +1,13 @@
 """Distance primitives shared by the K-means family.
 
 All bound arithmetic is fp32 (the filters must never prune the true
-nearest centroid); the bulk matmul term may run in bf16 on TPU via the
-Pallas kernel in ``repro.kernels`` — this module is the pure-jnp
-reference semantics used by the algorithm layer and the oracles.
+nearest centroid), and so is the cross term: every distance product
+runs at :data:`CROSS_PRECISION` (``HIGHEST``). The TPU's default f32
+matmul is one bf16 pass, whose error in ``x.c`` is comparable to the
+squared-distance gap between near-tied centroids: enough to flip
+labels and to let a bound prune the true nearest centroid. This module
+is the pure-jnp reference semantics used by the algorithm layer and
+the oracles.
 
 Every pairwise primitive accepts optional precomputed squared norms
 (``x2`` for rows, ``c2`` for centroids).  Point norms never change
@@ -16,7 +20,12 @@ bit-identical: the same ``sum(x*x)`` expression either way).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# precision of every distance cross term x.c (the engine's compact
+# pass, the serve assign and the Pallas kernel use the same)
+CROSS_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def row_norms_sq(x: jnp.ndarray) -> jnp.ndarray:
@@ -42,7 +51,8 @@ def pairwise_sq_dists(x: jnp.ndarray, c: jnp.ndarray,
         x2 = row_norms_sq(x)
     if c2 is None:
         c2 = row_norms_sq(c)
-    d2 = x2[:, None] - 2.0 * (x @ c.T) + c2[None, :]
+    d2 = x2[:, None] - 2.0 * jnp.dot(x, c.T, precision=CROSS_PRECISION) \
+        + c2[None, :]
     return jnp.maximum(d2, 0.0)                           # numerical floor
 
 

@@ -66,17 +66,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map moved out of jax.experimental (and check_rep was renamed
-# check_vma) across jax generations; support both so `import repro.core`
-# works everywhere. The flag disables the replication/vma check: psum
-# outputs are value-replicated but the static analysis cannot prove it
-# through the while_loop carry.
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-except ImportError:                      # jax >= 0.7
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
+# check_vma=False: psum outputs are value-replicated but the static
+# analysis cannot prove it through the while_loop carry.
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 from ..obs import ring as _obs_ring
 from ..obs.metrics import normalize_obs
@@ -125,7 +117,7 @@ def make_fit_sharded(mesh: Mesh, axes, k: int, n_groups: int,
     in_specs = (pspec, P(None, None)) + ((P(axes),) if weighted else ())
 
     @functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **_SHARD_MAP_KW)
+                       out_specs=out_specs)
     def fit_sharded(local_points, init_c, *rest):
         weights = rest[0] if weighted else None
         groups = group_centroids(init_c, n_groups)
@@ -198,7 +190,7 @@ def make_fit_sharded_engine(mesh: Mesh, axes, k: int, n_groups: int,
         (P(None, None), P(None), P(None, None), P(None))
 
     @functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **_SHARD_MAP_KW)
+                       out_specs=out_specs)
     def fit_sharded(local_points, valid, *rest):
         weights, rest = (rest[0], rest[1:]) if weighted else (None, rest)
         init_c, groups, members, gsize = rest
@@ -428,7 +420,11 @@ def distributed_yinyang(points, init_centroids, mesh: Mesh,
              jax.device_put(members, repl),
              jax.device_put(gsize, repl)]
     c, a, i, evals, inertia, rings = fit_sharded(*args)
-    result = KMeansResult(c, a[:n], i, evals, inertia)
+    if len(pts_in) != n:
+        # drop the sentinel rows; replicated first, the slice is
+        # unambiguous on meshes of either axis type (Auto or Explicit)
+        a = jax.device_put(a, repl)[:n]
+    result = KMeansResult(c, a, i, evals, inertia)
     if not want_stats:
         return result
     stats = _sharded_stats("compact", rings, int(i), n=n, k=k, cfg=cfg,
@@ -477,7 +473,6 @@ def make_stream_bounds_sharded(mesh: Mesh, axes: Sequence[str] = ("data",)):
         in_specs=(P(axes, None), P(None, None), P(axes), P(axes),
                   P(axes, None)),
         out_specs=(P(axes), P(axes), P(), P()),
-        **_SHARD_MAP_KW,
     )
     def bounds(points, centroids, assign, ub, lb):
         ub_t, need, n_cand, n_tight = stream_bounds(points, centroids,
@@ -520,7 +515,7 @@ def make_stream_update_sharded(mesh: Mesh, axes, *, k: int, n_groups: int,
     in_specs = base_specs + ((P(axes),) if weighted else ())
 
     @functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **_SHARD_MAP_KW)
+                       out_specs=out_specs)
     def update(points, centroids, counts, decay, groups, members,
                gsize, assignments, ub_t, lb, need, *rest):
         weights = rest[0] if weighted else None

@@ -64,9 +64,9 @@ Backend selection (``backend=`` on :func:`fit`):
     The two-level compaction path above. Default off-TPU: on CPU/GPU
     this is what turns filter rates into wall-clock speedup.
 ``"pallas"``
-    Group-granular block-skip Pallas kernel (``interpret=True`` runs it
-    anywhere). Default on TPU, where per-point gathers are hostile but
-    skipping whole (tile_n x group) blocks is free.
+    Group-granular block-skip Pallas kernel (compiled on TPU,
+    interpreted on CPU). Default on TPU, where per-point gathers are
+    hostile but skipping whole (tile_n x group) blocks is free.
 ``"lloyd"``
     The jit-cached reference Lloyd loop — one dense GEMM per
     iteration, no filter bookkeeping. The right call below the
@@ -111,8 +111,9 @@ from jax.experimental import io_callback
 from ..obs import ring as _obs_ring
 from ..obs.metrics import normalize_obs
 from ..obs.ring import N_COUNTERS, RING_COLUMNS
-from .distances import (pairwise_dists, pairwise_sq_dists, row_norms_sq,
-                        rowwise_dists)
+from ..platform import pallas_interpret
+from .distances import (CROSS_PRECISION, pairwise_dists,
+                        pairwise_sq_dists, row_norms_sq, rowwise_dists)
 from .kmeans import (EvalCount, KMeansResult, _init_filter_state,
                      centroid_sums, centroids_from_sums, group_centroids,
                      lloyd)
@@ -576,7 +577,8 @@ def compact_candidate_pass(points, new_c, assignments, ub_t, lb, groups,
             csel = new_c[mem_s]                          # (ch, cap_g, L, D)
             xf = x.astype(jnp.float32)
             cross = jnp.einsum("nd,ngld->ngl", xf,
-                               csel.astype(jnp.float32))
+                               csel.astype(jnp.float32),
+                               precision=CROSS_PRECISION)
             d2 = jnp.maximum(x2v[:, None, None] - 2.0 * cross + c2[mem_s],
                              0.0)
             ch = x.shape[0]
@@ -812,7 +814,9 @@ class EngineStats:
     construction (it is structural, not a runtime counter;
     ``tests/test_tune.py`` verifies it by counting real
     ``row_norms_sq`` calls); ``config`` is the resolved
-    :class:`EngineConfig` actually used.
+    :class:`EngineConfig` actually used; ``interpret`` whether the
+    Pallas kernel ran in the interpreter (always False off the pallas
+    backend and on the TPU).
 
     With observability enabled (``fit(obs=...)``) the stats carry the
     drained telemetry ring: ``ring`` is the trimmed
@@ -837,6 +841,7 @@ class EngineStats:
     init_evals: float = 0.0
     shard_rings: np.ndarray | None = None
     shard_skew: np.ndarray | None = None
+    interpret: bool = False
 
     def telemetry(self) -> dict | None:
         """Headline ring summary (iters, mean candidate fraction, total
@@ -864,6 +869,7 @@ class EngineStats:
             "x2_evals": int(self.x2_evals),
             "config": dict(self.config),
             "n_points": int(self.n_points),
+            "interpret": bool(self.interpret),
         }
         if self.ring is not None:
             out["ring_columns"] = list(self.ring_columns)
@@ -1379,8 +1385,8 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
     """Run filtered K-means fully device-resident.
 
     See the module docstring for backend semantics. ``interpret=None``
-    auto-enables Pallas interpreter mode off-TPU, so
-    ``backend='pallas'`` works (slowly) anywhere.
+    resolves through :func:`repro.platform.pallas_interpret`: compiled
+    on the TPU, interpreted (slowly) on the CPU backend.
 
     ``config`` pins an explicit :class:`EngineConfig`; ``tune``
     controls the per-(platform, N, K, D) autotuning cache
@@ -1451,14 +1457,14 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
             _publish_fit(obs_cfg, stats, res)
         return (res, stats) if return_stats else res
     if interpret is None:
-        interpret = backend == "pallas" and jax.default_backend() != "tpu"
+        interpret = backend == "pallas" and pallas_interpret()
     if n_groups is None:
         n_groups = max(k // 10, 1)
     n_groups = int(min(n_groups, k))
     tol = float(tol)
 
     stats = EngineStats(backend=backend, x2_evals=1, config=cfg.to_dict(),
-                        n_points=n)
+                        n_points=n, interpret=bool(interpret))
     cap_floor = min(cfg.min_cap, n)
 
     def _core(cap_n, cap_g, l_max):
@@ -1758,7 +1764,8 @@ def _serve_fused_impl(q, centroids, c2, *, chunk: int = 1024):
 
     def tile_fn(qt):
         # ||x||^2 omitted: constant per row, argmin-invariant
-        d2 = c2[None, :] - 2.0 * (qt @ centroids.T)
+        d2 = c2[None, :] - 2.0 * jnp.dot(qt, centroids.T,
+                                         precision=CROSS_PRECISION)
         mn = jnp.min(d2, axis=1, keepdims=True)
         return jnp.min(jnp.where(d2 <= mn, iota[None, :], k),
                        axis=1).astype(jnp.int32)
@@ -1800,7 +1807,7 @@ def serve_assign_grouped(q, centroids, c2, groups, members, gsize, *,
 
 
 def make_serve_assign(snapshot_shape, *, backend: str = "fused",
-                      chunk: int = 1024, interpret: bool = False,
+                      chunk: int = 1024, interpret: bool | None = None,
                       donate: bool | None = None):
     """Resolve the serve-side batched assign callable for a centroid
     snapshot shape ``(k, n_groups)``.
@@ -1809,8 +1816,9 @@ def make_serve_assign(snapshot_shape, *, backend: str = "fused",
     — a uniform signature over all backends (the fused path ignores
     the tables). ``backend``: ``"fused"`` (dense GEMM + min-trick, the
     CPU winner), ``"grouped"`` (PassCore compact pass over the group
-    tables), or ``"pallas"`` (the block-skip kernel; ``interpret=True``
-    off-TPU). All three are exact. ``donate`` (default: on except CPU,
+    tables), or ``"pallas"`` (the block-skip kernel; ``interpret=None``
+    resolves through :func:`repro.platform.pallas_interpret`). All
+    three are exact. ``donate`` (default: on except CPU,
     where donation is a no-op) donates the query buffer on the fused
     path — off-CPU this INVALIDATES a ``jax.Array`` the caller passes
     in ("Array has been deleted" on its next use), so only enable it
@@ -1831,6 +1839,8 @@ def make_serve_assign(snapshot_shape, *, backend: str = "fused",
     if backend not in ("grouped", "pallas"):
         raise ValueError(f"unknown serve backend {backend!r}")
     pc_backend = "pallas" if backend == "pallas" else "compact"
+    if interpret is None:
+        interpret = backend == "pallas" and pallas_interpret()
 
     def run(q, centroids, c2, groups, members, gsize):
         core = PassCore(backend=pc_backend, k=k, n_groups=n_groups,

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for KPynq (validated via interpret=True on CPU)."""
+"""Pallas TPU kernels for KPynq (compiled on the TPU, interpreted on the
+CPU: see ``repro.platform.pallas_interpret``)."""
 from .flash_attention import flash_attention
 from .ssd_intra import ssd_intra
 from .ops import (build_block_mask, build_group_block_mask,

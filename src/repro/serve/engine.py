@@ -114,13 +114,13 @@ class ServeEngine:
 
     def __init__(self, index: CentroidIndex, *,
                  config: ServeConfig | None = None, tune: str = "on",
-                 obs=None, interpret: bool | None = None):
+                 obs=None):
         self._index = index
         self._cfg = config
         self._tune = tune
         self._obs = normalize_obs(obs)
-        self._interpret = interpret
         self._q: queue.Queue = queue.Queue()
+        self._held: _Request | None = None   # opens the next batch
         self._thread: threading.Thread | None = None
         self._running = False
         self._buffers: dict = {}        # bucket -> reused (bucket, D) f32
@@ -255,18 +255,16 @@ class ServeEngine:
         fn = self._assigns.get(key)
         if fn is None:
             cfg = self._config()
-            interpret = self._interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
             fn = _engine.make_serve_assign(
                 (snap.k, snap.n_groups), backend=cfg.backend,
-                chunk=cfg.chunk, interpret=interpret, donate=donate)
+                chunk=cfg.chunk, donate=donate)
             self._assigns[key] = fn
         return fn
 
     def _drain(self, first: _Request) -> list:
         """Coalesce up to max_batch points, optionally lingering
-        ``max_wait_us`` for batch fill."""
+        ``max_wait_us`` for batch fill. A request that would overflow
+        max_batch is held back to open the next batch."""
         cfg = self._config()
         reqs = [first]
         total = first.points.shape[0]
@@ -284,6 +282,9 @@ class ServeEngine:
                     break
             if nxt is None:             # stop sentinel: put it back
                 self._q.put(None)
+                break
+            if total + nxt.points.shape[0] > cfg.max_batch:
+                self._held = nxt
                 break
             reqs.append(nxt)
             total += nxt.points.shape[0]
@@ -357,12 +358,15 @@ class ServeEngine:
 
     def _loop(self) -> None:
         while True:
-            try:
-                first = self._q.get(timeout=0.05)
-            except queue.Empty:
-                if not self._running:
-                    return
-                continue
+            if self._held is not None:  # overflowed the last batch
+                first, self._held = self._held, None
+            else:
+                try:
+                    first = self._q.get(timeout=0.05)
+                except queue.Empty:
+                    if not self._running:
+                        return
+                    continue
             if first is None:
                 if self._running:       # spurious wake
                     continue

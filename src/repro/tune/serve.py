@@ -120,14 +120,10 @@ def autotune_serve(*, k: int, d: int, backends=None,
     best_cfg, best_t = DEFAULT_SERVE_CONFIG, float("inf")
     for backend in backends:
         for chunk in chunks:
-            fn = _engine.make_serve_assign(
-                shape, backend=backend, chunk=int(chunk),
-                interpret=jax.default_backend() != "tpu")
-            try:
-                jax.block_until_ready(
-                    fn(q, centroids, c2, groups, members, gsize))
-            except Exception:       # backend unavailable on this platform
-                continue
+            fn = _engine.make_serve_assign(shape, backend=backend,
+                                           chunk=int(chunk))
+            jax.block_until_ready(      # warm-up; a backend error raises
+                fn(q, centroids, c2, groups, members, gsize))
             t_best = float("inf")
             for _ in range(repeats):
                 t0 = time.perf_counter()

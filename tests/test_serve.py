@@ -193,6 +193,28 @@ def test_engine_jumbo_request_split_and_exact():
         assert np.array_equal(labels, _dense_labels(q, centroids))
 
 
+def test_engine_coalescing_never_overflows_max_batch():
+    """Many ragged requests queued at once: coalescing stops before a
+    request that would overflow max_batch (it opens the next batch), so
+    every request is answered exactly and in submission order."""
+    d, k = 8, 16
+    q = _mk(4096, d, 0)
+    centroids = _mk(k, d, 1)
+    idx = CentroidIndex(centroids)
+    cfg = ServeConfig(min_bucket=64, max_batch=512)
+    rng = np.random.default_rng(2)
+    spans = [(int(rng.integers(0, 3500)), int(rng.integers(100, 500)))
+             for _ in range(40)]
+    with ServeEngine(idx, config=cfg, tune="off") as eng:
+        futs = [eng.submit(q[lo:lo + m]) for lo, m in spans]
+        results = [f.result(timeout=60) for f in futs]
+        assert eng.points == sum(m for _, m in spans)
+        assert eng.batches < len(spans)     # requests were coalesced
+    for (lo, m), (labels, _) in zip(spans, results):
+        assert np.array_equal(labels, _dense_labels(q[lo:lo + m],
+                                                    centroids))
+
+
 def test_engine_device_resident_submit_exact():
     """A device-resident f32 jax.Array block skips host staging (the
     exact-fit path feeds it straight to the jitted assign) and yields

@@ -1,0 +1,124 @@
+"""Compile the main path's TPU programs for a described v5e, no chip.
+
+The TPU compiler is installed with jax and compiles for a topology that
+is described rather than attached. What it refuses here (a block shape
+off the (8, 128) tiling, more fast memory than a kernel may use, a
+program that does not fit the device) it would refuse on the chip; what
+it accepts says nothing about results or speed.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and each
+xdist worker imports every test file. The persistent compilation cache
+is off around these compiles (an entry written for a described chip
+cannot be read back without one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.kpynq import paper_suite
+from repro.core import engine as _engine
+from repro.core.distributed import make_fit_sharded_engine
+from repro.kernels import grouped_assign
+
+LMAX = 24          # the largest centroid group the shapes below assume
+
+
+def _problem(name):
+    prob = next(p for p in paper_suite if p.name == name)
+    return prob.n_points, prob.n_dims, prob.k, prob.n_groups or prob.k // 10
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topology = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topology
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding):
+    """Shape-and-dtype specs placed on ``sharding``."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+@pytest.mark.parametrize("name", ["uci-xlarge", "uci-wide"])
+def test_grouped_assign_compiles_for_v5e(one_chip, name):
+    """The block-skip kernel at a real width: every BlockSpec on the
+    tiling, the mask in SMEM, and a Mosaic kernel in the program."""
+    n, d, k, g = _problem(name)
+    tile_n = 256
+    s = _on(one_chip)
+    fn = jax.jit(lambda x, c, ids, m, x2, c2: grouped_assign(
+        x, c, ids, m, tile_n=tile_n, interpret=False, x2=x2, c2g=c2))
+    compiled = fn.lower(
+        s((n, d), jnp.float32), s((g, LMAX, d), jnp.float32),
+        s((g, LMAX), jnp.int32), s((n // tile_n, g), jnp.bool_),
+        s((n,), jnp.float32), s((g, LMAX), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_candidate_pass_compiles_for_v5e(one_chip):
+    """The engine's pallas candidate pass (mask build, grouped centroid
+    gather, kernel, lower-bound refresh) jitted at uci-xlarge."""
+    n, d, k, g = _problem("uci-xlarge")
+    s = _on(one_chip)
+    fn = jax.jit(lambda *a: _engine.pallas_candidate_pass(
+        *a[:9], n_groups=g, tile_n=256, interpret=False, x2=a[9],
+        c2=a[10]))
+    compiled = fn.lower(
+        s((n, d), jnp.float32), s((k, d), jnp.float32),
+        s((n,), jnp.int32), s((n,), jnp.float32), s((n, g), jnp.float32),
+        s((k,), jnp.int32), s((g, LMAX), jnp.int32), s((g,), jnp.float32),
+        s((n,), jnp.bool_), s((n,), jnp.float32),
+        s((k,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the (N, G) bounds and the kernel's outputs fit one 16 GB chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_serve_fused_assign_compiles_for_v5e(one_chip):
+    """The default serve backend at a full 8,192-row bucket, K=256."""
+    s = _on(one_chip)
+    compiled = _engine.serve_assign_fused.lower(
+        s((8192, 32), jnp.float32), s((256, 32), jnp.float32),
+        s((256,), jnp.float32), chunk=1024).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_sharded_engine_fit_compiles_for_four_chips(topo):
+    """The compact sharded fit (capacity ladder under shard_map, psum of
+    the centroid sums) over a 4-chip mesh of the described devices."""
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n, d, k, g = 65_536, 32, 256, 25
+
+    def s(shape, dtype, spec):
+        return _on(NamedSharding(mesh, spec))(shape, dtype)
+    fn = jax.jit(make_fit_sharded_engine(mesh, ("data",), k, g, 50, 1e-4,
+                                         shard_n=n // 4))
+    compiled = fn.lower(
+        s((n, d), jnp.float32, P("data", None)),
+        s((n,), jnp.bool_, P("data")), s((k, d), jnp.float32, P()),
+        s((k,), jnp.int32, P()), s((g, LMAX), jnp.int32, P()),
+        s((g,), jnp.float32, P())).compile()
+    assert "all-reduce" in compiled.as_text()
