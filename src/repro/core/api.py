@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import compile_count, span
 from . import engine as _engine
 from . import kmeans as _km
 from .init import kmeans_plusplus, random_init
@@ -66,7 +67,15 @@ class KMeans:
 
     After an engine-path :meth:`fit`, ``stats_`` holds the
     :class:`repro.core.engine.EngineStats` (telemetry ring included
-    when ``obs`` is enabled); ``None`` otherwise.
+    when ``obs`` is enabled; ``compiles`` counts the programs the whole
+    call lowered, seeding included); ``None`` otherwise.
+
+    :meth:`fit` opens host spans (:func:`repro.obs.span`) that a
+    recording profiler places on the device ops' clock: ``kpynq.fit``
+    around the call, ``kpynq.seed`` around the seeding and
+    ``kpynq.fetch`` around the result's transfer to the host, with the
+    engine's ``kpynq.tables``, ``kpynq.loop`` and ``kpynq.epilogue``
+    between them.
     """
 
     def __init__(self, n_clusters: int, algorithm: str = "yinyang",
@@ -115,28 +124,33 @@ class KMeans:
         weight-independent, so the work saving is unchanged. ``None``
         is bit-identical to uniform weights of 1.0 for the fit and
         runs the seed's original seeding program."""
-        points = jnp.asarray(points)
-        weights = None if sample_weight is None else \
-            jnp.asarray(sample_weight, jnp.float32)
-        init_c = self._init_centroids(points, weights)
-        self.stats_ = None        # only engine-path fits produce stats
-        if self.algorithm == "lloyd":
-            res = _km.lloyd(points, init_c, self.max_iters, self.tol,
-                            weights=weights)
-        else:
-            n_groups = 1 if self.algorithm == "hamerly" else self.n_groups
-            if self.engine is None:
-                res = _km.yinyang(points, init_c, n_groups=n_groups,
-                                  max_iters=self.max_iters, tol=self.tol,
-                                  weights=weights)
+        with span("kpynq.fit"):
+            compiles0 = compile_count()
+            points = jnp.asarray(points)
+            weights = None if sample_weight is None else \
+                jnp.asarray(sample_weight, jnp.float32)
+            with span("kpynq.seed"):
+                init_c = self._init_centroids(points, weights)
+            self.stats_ = None    # only engine-path fits produce stats
+            if self.algorithm == "lloyd":
+                res = _km.lloyd(points, init_c, self.max_iters, self.tol,
+                                weights=weights)
             else:
-                out = _engine.fit(points, init_c, n_groups=n_groups,
-                                  max_iters=self.max_iters, tol=self.tol,
-                                  backend=self.engine, tune=self.tune,
-                                  sample_weight=weights, obs=self.obs,
-                                  return_stats=True)
-                res, self.stats_ = out
-        self.result_ = jax.tree.map(jax.device_get, res)
+                n_groups = 1 if self.algorithm == "hamerly" \
+                    else self.n_groups
+                if self.engine is None:
+                    res = _km.yinyang(points, init_c, n_groups=n_groups,
+                                      max_iters=self.max_iters, tol=self.tol,
+                                      weights=weights)
+                else:
+                    res, self.stats_ = _engine.fit(
+                        points, init_c, n_groups=n_groups,
+                        max_iters=self.max_iters, tol=self.tol,
+                        backend=self.engine, tune=self.tune,
+                        sample_weight=weights, obs=self.obs,
+                        return_stats=True, compiles_since=compiles0)
+            with span("kpynq.fetch"):
+                self.result_ = jax.tree.map(jax.device_get, res)
         self._stream = None       # a batch fit supersedes any stream state
         self._assign_tables = None
         return self

@@ -111,6 +111,7 @@ from jax.experimental import io_callback
 from ..obs import ring as _obs_ring
 from ..obs.metrics import normalize_obs
 from ..obs.ring import N_COUNTERS, RING_COLUMNS
+from ..obs.trace import compile_count, span
 from ..platform import pallas_interpret
 from .distances import (CROSS_PRECISION, pairwise_dists,
                         pairwise_sq_dists, row_norms_sq, rowwise_dists)
@@ -816,7 +817,10 @@ class EngineStats:
     ``row_norms_sq`` calls); ``config`` is the resolved
     :class:`EngineConfig` actually used; ``interpret`` whether the
     Pallas kernel ran in the interpreter (always False off the pallas
-    backend and on the TPU).
+    backend and on the TPU); ``compiles`` the programs lowered during
+    the fit (:func:`repro.obs.compile_count`; through ``KMeans.fit``,
+    its seeding included): each a compile or a persistent-cache load,
+    so a non-zero count on a repeated fit of one shape is a recompile.
 
     With observability enabled (``fit(obs=...)``) the stats carry the
     drained telemetry ring: ``ring`` is the trimmed
@@ -842,6 +846,7 @@ class EngineStats:
     shard_rings: np.ndarray | None = None
     shard_skew: np.ndarray | None = None
     interpret: bool = False
+    compiles: int = 0
 
     def telemetry(self) -> dict | None:
         """Headline ring summary (iters, mean candidate fraction, total
@@ -870,6 +875,7 @@ class EngineStats:
             "config": dict(self.config),
             "n_points": int(self.n_points),
             "interpret": bool(self.interpret),
+            "compiles": int(self.compiles),
         }
         if self.ring is not None:
             out["ring_columns"] = list(self.ring_columns)
@@ -1132,15 +1138,16 @@ def _epilogue_pass(core: PassCore, points, weights, valid, carry, groups,
             carry.need, groups, members, gsize, x2=carry.x2, c2=carry.c2,
             level_n=level_n, level_g=level_g)
     evals = core.reducer.add(carry.evals.add(pairs).total())
-    own = carry.centroids[jnp.minimum(new_as, core.k - 1)]
-    d = rowwise_dists(points, own)
-    d2 = d * d
-    if valid is not None:
-        d2 = jnp.where(valid, d2, 0.0)
-    if weights is not None:
-        d2 = d2 * weights
-    local_inertia = jnp.sum(d2)
-    inertia = core.reducer.add(local_inertia)
+    with jax.named_scope("kpynq/inertia"):
+        own = carry.centroids[jnp.minimum(new_as, core.k - 1)]
+        d = rowwise_dists(points, own)
+        d2 = d * d
+        if valid is not None:
+            d2 = jnp.where(valid, d2, 0.0)
+        if weights is not None:
+            d2 = d2 * weights
+        local_inertia = jnp.sum(d2)
+        inertia = core.reducer.add(local_inertia)
     ring = carry.ring
     if core.ring_iters:
         with jax.named_scope("kpynq/ring_write"):
@@ -1236,8 +1243,9 @@ def _init_carry(points, init_c, groups, *, n_groups, ring_iters=0):
     n = points.shape[0]
     x2 = row_norms_sq(points)
     c2 = row_norms_sq(init_c.astype(jnp.float32))
-    state0 = _init_filter_state(points, init_c, groups, n_groups,
-                                x2=x2, c2=c2)
+    with jax.named_scope("kpynq/init"):      # the dense first assignment
+        state0 = _init_filter_state(points, init_c, groups, n_groups,
+                                    x2=x2, c2=c2)
     return EngineCarry(
         jnp.int32(0), state0.centroids, c2, state0.assignments, state0.ub,
         state0.lb, x2, jnp.zeros((n,), bool), jnp.int32(0), jnp.int32(0),
@@ -1365,8 +1373,12 @@ def _publish_fit(obs_cfg, stats: EngineStats, result) -> None:
               labels=labels).set(float(stats.n_iters))
     reg.gauge("engine_last_host_syncs", "host syncs of the last fit",
               labels=labels).set(float(stats.host_syncs))
+    reg.counter("engine_compiles_total",
+                "programs lowered (compiled or loaded) during fits",
+                labels=labels).inc(float(stats.compiles))
     evt = {"backend": stats.backend, "n_iters": stats.n_iters,
-           "host_syncs": stats.host_syncs, "n_points": stats.n_points,
+           "host_syncs": stats.host_syncs, "compiles": stats.compiles,
+           "n_points": stats.n_points,
            "distance_evals": float(result.distance_evals),
            "inertia": float(result.inertia)}
     tel = stats.telemetry()
@@ -1381,7 +1393,7 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
         chunk: int | None = None, interpret: bool | None = None,
         max_bucket_switches: int = 32, return_stats: bool = False,
         config: EngineConfig | None = None, tune: str = "auto",
-        sample_weight=None, obs=None):
+        sample_weight=None, obs=None, compiles_since: int | None = None):
     """Run filtered K-means fully device-resident.
 
     See the module docstring for backend semantics. ``interpret=None``
@@ -1411,6 +1423,10 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
     and the fit publishes counters + an ``engine_fit`` event into the
     registry. Results are bit-identical with obs on or off.
 
+    ``compiles_since``: a :func:`repro.obs.compile_count` reading from
+    which ``EngineStats.compiles`` counts (default: this call's start;
+    ``KMeans.fit`` passes the reading from before its seeding).
+
     Returns a :class:`~repro.core.kmeans.KMeansResult`; with
     ``return_stats=True`` returns ``(result, EngineStats)``.
     """
@@ -1421,6 +1437,8 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
     if tune not in ("auto", "off", "force"):
         raise ValueError(f"unknown tune mode {tune!r}; expected "
                          f"'auto', 'off' or 'force'")
+    if compiles_since is None:
+        compiles_since = compile_count()
     points = jnp.asarray(points)
     init_c = jnp.asarray(init_centroids)
     if init_c.dtype != jnp.float32:
@@ -1450,7 +1468,8 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
                                     # no stats blocking / dict building
         stats = EngineStats(backend="lloyd", n_iters=int(res.n_iters),
                             host_syncs=1, config=cfg.to_dict(),
-                            n_points=n)
+                            n_points=n,
+                            compiles=compile_count() - compiles_since)
         if obs_cfg is not None:
             # the dense loop has no filter pass, hence no ring — the
             # registry still gets the fit event/counters
@@ -1498,16 +1517,17 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
         result = KMeansResult(c, a, it, evals, inertia)
         if ring_iters:
             _drain_ring(ring)
+        stats.compiles = compile_count() - compiles_since
         if obs_cfg is not None:
             _publish_fit(obs_cfg, stats, result)
         return (result, stats) if return_stats else result
 
-    groups = group_centroids(init_c, n_groups)
-
-    # group membership table (G, Lmax), -1-padded; one setup-time sync
-    groups_np = np.asarray(jax.device_get(groups))
-    stats.host_syncs += 1
-    members, gsize = build_group_tables(groups_np, n_groups)
+    with span("kpynq.tables"):
+        groups = group_centroids(init_c, n_groups)
+        # group membership table (G, Lmax), -1-padded; one setup-time sync
+        groups_np = np.asarray(jax.device_get(groups))
+        stats.host_syncs += 1
+        members, gsize = build_group_tables(groups_np, n_groups)
     l_max = int(members.shape[1])
 
     carry = _init_carry(points, init_c, groups, n_groups=n_groups,
@@ -1524,11 +1544,13 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
         if backend == "compact":
             stats.use_groups.append(bool(core.use_groups))
         allow_down = stats.bucket_switches < max_bucket_switches
-        carry = _run_loop(points, weights, carry, groups, members, gsize,
-                          core=core, max_iters=int(max_iters), tol=tol,
-                          min_cap=cap_floor, allow_downshift=allow_down)
-        it, nc, gm, sh = jax.device_get(
-            (carry.iteration, carry.n_cand, carry.gmax, carry.shift))
+        with span("kpynq.loop"):
+            carry = _run_loop(points, weights, carry, groups, members,
+                              gsize, core=core, max_iters=int(max_iters),
+                              tol=tol, min_cap=cap_floor,
+                              allow_downshift=allow_down)
+            it, nc, gm, sh = jax.device_get(
+                (carry.iteration, carry.n_cand, carry.gmax, carry.shift))
         stats.host_syncs += 1
         if int(it) >= max_iters or float(sh) <= tol:
             break
@@ -1554,14 +1576,16 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
         ecap_g = _bucket_cap(int(gm), 1, n_groups)
     else:
         ecap_n, ecap_g = n, n_groups
-    assignments, evals, inertia, ring = _epilogue(
-        points, weights, carry, groups, members, gsize,
-        core=_core(ecap_n, ecap_g, l_max))
+    with span("kpynq.epilogue"):
+        assignments, evals, inertia, ring = _epilogue(
+            points, weights, carry, groups, members, gsize,
+            core=_core(ecap_n, ecap_g, l_max))
 
     result = KMeansResult(carry.centroids, assignments, carry.iteration,
                           evals, inertia)
     if ring_iters:
         _drain_ring(ring)
+    stats.compiles = compile_count() - compiles_since
     if obs_cfg is not None:
         _publish_fit(obs_cfg, stats, result)
     if return_stats:

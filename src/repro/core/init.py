@@ -24,6 +24,9 @@ def kmeans_plusplus(key: jax.Array, points: jnp.ndarray, k: int,
     collapses m duplicates into one index). ``weights=None`` keeps the
     seed's original program — uniform first draw via randint, plain D^2
     after — so existing fits stay bit-identical.
+
+    The draws' device ops run under the ``kpynq/seed`` scope (the first
+    draw's few eager ops fall in none).
     """
     n = points.shape[0]
     pts = points.astype(jnp.float32)
@@ -40,17 +43,20 @@ def kmeans_plusplus(key: jax.Array, points: jnp.ndarray, k: int,
     min_d2 = pairwise_sq_dists(pts, first[None])[:, 0]
 
     def body(i, carry):
-        key, centroids, min_d2 = carry
-        key, sub = jax.random.split(key)
-        # Sample proportional to (w *) D^2 (guard the all-zero corner).
-        scores = min_d2 if w is None else w * min_d2
-        probs = jnp.where(jnp.sum(scores) > 0, scores,
-                          jnp.ones_like(scores) if w is None else wp)
-        idx = jax.random.categorical(sub, jnp.log(probs + 1e-30))
-        c = pts[idx]
-        centroids = centroids.at[i].set(c)
-        d2 = pairwise_sq_dists(pts, c[None])[:, 0]
-        return key, centroids, jnp.minimum(min_d2, d2)
+        # the scope sits in the body: a loop called eagerly is traced
+        # under a fresh name stack, which drops any scope around the call
+        with jax.named_scope("kpynq/seed"):
+            key, centroids, min_d2 = carry
+            key, sub = jax.random.split(key)
+            # Sample proportional to (w *) D^2 (guard the all-zero corner).
+            scores = min_d2 if w is None else w * min_d2
+            probs = jnp.where(jnp.sum(scores) > 0, scores,
+                              jnp.ones_like(scores) if w is None else wp)
+            idx = jax.random.categorical(sub, jnp.log(probs + 1e-30))
+            c = pts[idx]
+            centroids = centroids.at[i].set(c)
+            d2 = pairwise_sq_dists(pts, c[None])[:, 0]
+            return key, centroids, jnp.minimum(min_d2, d2)
 
     _, centroids, _ = jax.lax.fori_loop(1, k, body, (key, centroids, min_d2))
     return centroids

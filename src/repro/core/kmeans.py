@@ -81,21 +81,24 @@ def group_centroids(centroids: jnp.ndarray, n_groups: int, n_iters: int = 5):
     strided subset. Returns int32 group ids of shape (K,).
 
     Jitted (it is called eagerly by every fit driver, and an un-jitted
-    ``fori_loop`` costs ~100ms of per-op dispatch even for tiny K)."""
+    ``fori_loop`` costs ~100ms of per-op dispatch even for tiny K). Its
+    device ops run under the ``kpynq/group`` scope."""
     k = centroids.shape[0]
     if n_groups >= k:
         return jnp.arange(k, dtype=jnp.int32) % n_groups
     stride = max(k // n_groups, 1)
-    seeds = centroids[::stride][:n_groups]
+    with jax.named_scope("kpynq/group"):
+        seeds = centroids[::stride][:n_groups]
 
-    def body(_, seeds):
-        d = pairwise_dists(centroids, seeds)
-        gid = jnp.argmin(d, axis=1)
-        new_seeds, _ = update_centroids(centroids, gid, n_groups, seeds)
-        return new_seeds
+        def body(_, seeds):
+            d = pairwise_dists(centroids, seeds)
+            gid = jnp.argmin(d, axis=1)
+            new_seeds, _ = update_centroids(centroids, gid, n_groups, seeds)
+            return new_seeds
 
-    seeds = jax.lax.fori_loop(0, n_iters, body, seeds)
-    return jnp.argmin(pairwise_dists(centroids, seeds), axis=1).astype(jnp.int32)
+        seeds = jax.lax.fori_loop(0, n_iters, body, seeds)
+        return jnp.argmin(pairwise_dists(centroids, seeds),
+                          axis=1).astype(jnp.int32)
 
 
 class EvalCount(NamedTuple):

@@ -169,6 +169,7 @@ def grouped_assign(x: jnp.ndarray, c_grouped: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="grouped_assign",        # the kernel's name in a profile
     )(mask, xp, x2p, c_grouped.astype(jnp.float32), c2g,
       ids.astype(jnp.int32)[:, :, None])
     return (best[0, :n], idx[0, :n], gmin[:, :n].T, garg[:, :n].T,

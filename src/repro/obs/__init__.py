@@ -9,7 +9,8 @@ Three layers (see ``docs/observability.md``):
   host-side semantics.
 * :mod:`repro.obs.trace` — phase tracing: ``jax.named_scope`` device
   phases (annotated in the engine), :func:`profile` for Perfetto
-  traces, :func:`span` for host wall-clock spans.
+  traces, :func:`span` for host spans on the profiler's clock,
+  :func:`compile_count` for the programs the process has lowered.
 * :mod:`repro.obs.metrics` — the metrics registry
   (counter/gauge/histogram + JSONL event log) with Prometheus-text and
   JSONL exporters, published by all three fit drivers.
@@ -23,7 +24,7 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from .ring import (N_COUNTERS, RING_COLUMNS, add_ring_listener,
                    caps_from_ring, format_ring_table, reduce_shard_rings,
                    remove_ring_listener, shard_skew, summarize_ring)
-from .trace import profile, span
+from .trace import compile_count, profile, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "ObsConfig",
@@ -32,5 +33,5 @@ __all__ = [
     "N_COUNTERS", "RING_COLUMNS", "add_ring_listener", "caps_from_ring",
     "format_ring_table", "reduce_shard_rings", "remove_ring_listener",
     "shard_skew", "summarize_ring",
-    "profile", "span",
+    "compile_count", "profile", "span",
 ]

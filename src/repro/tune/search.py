@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.engine import EngineConfig
+from ..obs.metrics import default_registry
 from ..obs.trace import span
 from .cache import TuneCache, default_cache
 from .signature import signature
@@ -199,8 +200,8 @@ def autotune(points, init_c, *, n_groups=None, max_iters: int = 50,
         if key not in memo:
             if len(memo) >= max_measurements:
                 return float("inf")
-            with span("tune.measure", sig=sig,
-                      backend=cfg.backend) as fields:
+            with span("tune.measure", registry=default_registry(),
+                      sig=sig, backend=cfg.backend) as fields:
                 memo[key] = float(measure(cfg))
                 fields["best_s"] = memo[key]
             if verbose:

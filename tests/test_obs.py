@@ -25,9 +25,10 @@ from repro.core import engine, kmeans_plusplus
 from repro.core.api import KMeans
 from repro.data import make_points
 from repro.obs import (MetricsRegistry, ObsConfig, add_ring_listener,
-                       caps_from_ring, normalize_obs, provenance,
-                       reduce_shard_rings, remove_ring_listener,
-                       shard_skew, span, summarize_ring)
+                       caps_from_ring, compile_count, default_registry,
+                       normalize_obs, provenance, reduce_shard_rings,
+                       remove_ring_listener, shard_skew, span,
+                       summarize_ring)
 from repro.obs.ring import (COL_EVALS, COL_INERTIA, COL_N_CAND,
                             N_COUNTERS, RING_COLUMNS)
 from repro.runtime.fault_tolerance import StragglerWatchdog
@@ -253,6 +254,92 @@ def test_registry_jsonl_export_and_span(tmp_path):
     hist = reg.histogram("span_seconds",
                          labels={"span": "unit.region"})
     assert hist.count == 1
+
+
+def test_span_without_registry_records_nothing():
+    reg = default_registry()
+    before = len(reg.events)
+    with span("unit.unrecorded", tag="x") as s:
+        s["result"] = 1
+    assert len(reg.events) == before
+    assert "unit.unrecorded" not in reg.to_prometheus()
+
+
+# -------------------------------------------------------------------------
+# host spans on the profiler's clock, compile counter
+# -------------------------------------------------------------------------
+
+FIT_PHASES = ("kpynq.seed", "kpynq.tables", "kpynq.loop", "kpynq.epilogue",
+              "kpynq.fetch")
+
+
+def _host_spans(trace_dir):
+    """``[(name, start_ns, end_ns)]`` of the ``kpynq.`` host events."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.end_ns) for plane in pd.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events if e.name.startswith("kpynq.")]
+
+
+def test_kmeans_fit_spans_land_in_the_profile(tmp_path):
+    pts, _ = _dataset()             # 1500 points: engine.fit's bucketed path
+    km = KMeans(12, engine="compact", max_iters=25, tune="off")
+    with jax.profiler.trace(str(tmp_path)):
+        km.fit(pts)
+    spans = _host_spans(tmp_path)
+    fits = [(s, e) for n, s, e in spans if n == "kpynq.fit"]
+    assert len(fits) == 1
+    lo, hi = fits[0]
+    names = {n for n, _, _ in spans}
+    assert set(FIT_PHASES) <= names
+    for name, s, e in spans:
+        assert lo <= s <= e <= hi, name
+    # one loop span per capacity-bucket segment
+    assert sum(n == "kpynq.loop" for n, _, _ in spans) == \
+        len(km.stats_.caps_history)
+
+
+def test_kmeans_fit_bit_identical_under_the_profiler(tmp_path):
+    pts, _ = _dataset()
+    plain = KMeans(12, engine="compact", max_iters=25, tune="off").fit(pts)
+    traced = KMeans(12, engine="compact", max_iters=25, tune="off")
+    with jax.profiler.trace(str(tmp_path)):
+        traced.fit(pts)
+    np.testing.assert_array_equal(np.asarray(plain.cluster_centers_),
+                                  np.asarray(traced.cluster_centers_))
+    np.testing.assert_array_equal(np.asarray(plain.labels_),
+                                  np.asarray(traced.labels_))
+
+
+def test_compile_count_counts_a_fresh_jit_once():
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = jnp.arange(7, dtype=jnp.float32).block_until_ready()
+    c0 = compile_count()
+    f(x).block_until_ready()
+    c1 = compile_count()
+    f(x).block_until_ready()
+    assert c1 - c0 == 1
+    assert compile_count() == c1
+
+
+def test_kmeans_stats_count_compiles_and_publish_them():
+    pts, _ = _dataset()
+    reg = MetricsRegistry()
+    km = KMeans(12, engine="compact", max_iters=25, tune="off", obs=reg)
+    km.fit(pts)
+    n = km.stats_.compiles
+    assert isinstance(n, int) and n >= 0
+    assert km.stats_.to_dict()["compiles"] == n
+    total = reg.counter("engine_compiles_total",
+                        labels={"backend": "compact"}).value
+    assert total == n
+    evt, = [e for e in reg.events if e["event"] == "engine_fit"]
+    assert evt["compiles"] == n
 
 
 def test_normalize_obs_coercions():
