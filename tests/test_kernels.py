@@ -1,5 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (Pallas interpret=True executes the kernel body on CPU)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,9 @@ from repro.kernels import (build_block_mask, build_group_block_mask,
                            grouped_assign, pairwise_sq_dists)
 from repro.kernels.ref import (centroid_update_ref, filtered_assign_ref,
                                grouped_assign_ref, pairwise_sq_dists_ref)
+
+# the module, which the package's function of the same name shadows
+grouped_assign_mod = importlib.import_module("repro.kernels.grouped_assign")
 
 SHAPES = [  # (n, d, k) including non-aligned sizes that exercise padding
     (256, 16, 128), (1000, 48, 300), (130, 7, 17), (512, 128, 128),
@@ -51,18 +56,28 @@ def test_filtered_assign_block_skip(n, d, k, density):
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(iref))
 
 
-@pytest.mark.parametrize("n,d,k,g,tile_n,density", [
-    (300, 7, 17, 4, 128, 0.5),    # ragged N/K, partial skip
-    (512, 16, 64, 8, 256, 1.0),   # aligned, fully dense
-    (1000, 12, 40, 5, 256, 0.3),  # mostly skipped
-    (130, 3, 6, 6, 64, 0.0),      # everything skipped
+@pytest.mark.parametrize("n,d,k,g,tile_n,density,lmax,budget", [
+    (300, 7, 17, 4, 128, 0.5, None, None),    # ragged N/K, partial skip
+    (512, 16, 64, 8, 256, 1.0, None, None),   # aligned, fully dense
+    (1000, 12, 40, 5, 256, 0.3, None, None),  # mostly skipped
+    (130, 3, 6, 6, 64, 0.0, None, None),      # everything skipped
+    # a budget too small for one group: 8 groups a step, G=20 leaves a
+    # partial last group block
+    (384, 8, 60, 20, 128, 0.5, None, 1),
+    (520, 10, 64, 8, 256, 0.6, 32, None),     # Lmax 4x the mean group
+    (512, 16, 64, 8, 256, "halves", None, None),  # dead tile, live tile
 ])
-def test_grouped_assign_matches_ref(n, d, k, g, tile_n, density):
+def test_grouped_assign_matches_ref(monkeypatch, n, d, k, g, tile_n,
+                                    density, lmax, budget):
+    if budget is not None:
+        monkeypatch.setattr(grouped_assign_mod, "GROUP_VMEM_BUDGET", budget)
     kx, kc, kg, km = jax.random.split(jax.random.PRNGKey(n + k), 4)
     x = jax.random.normal(kx, (n, d))
     c = jax.random.normal(kc, (k, d))
     groups = np.asarray(jax.random.randint(kg, (k,), 0, g))
-    lmax = max(int(np.bincount(groups, minlength=g).max()), 1)
+    lmax = max(int(np.bincount(groups, minlength=g).max()), lmax or 1)
+    gs = grouped_assign_mod.groups_per_step(g, lmax, d, tile_n)
+    assert gs < g if budget is not None else gs == g
     members = np.full((g, lmax), -1, np.int32)
     for gg in range(g):
         ids = np.nonzero(groups == gg)[0]
@@ -70,7 +85,10 @@ def test_grouped_assign_matches_ref(n, d, k, g, tile_n, density):
     ids = jnp.asarray(members)
     c_grouped = c[jnp.maximum(ids, 0)]
     gn = -(-n // tile_n)
-    mask = jax.random.bernoulli(km, density, (gn, g))
+    if density == "halves":    # even tiles need no group, odd ones all
+        mask = jnp.repeat((jnp.arange(gn) % 2 == 1)[:, None], g, axis=1)
+    else:
+        mask = jax.random.bernoulli(km, density, (gn, g))
     got = grouped_assign(x, c_grouped, ids, mask, tile_n=tile_n,
                          interpret=True)
     want = grouped_assign_ref(x, c_grouped, ids, mask, tile_n)
@@ -84,6 +102,20 @@ def test_grouped_assign_matches_ref(n, d, k, g, tile_n, density):
                                        atol=1e-5, err_msg=name)
         else:
             np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_groups_per_step_rule():
+    """All groups in one step at the IVF1024 cell's shape (D=128, G=102,
+    Lmax 72); at K=16,384 (G=1,638) the largest multiple of 8 whose
+    blocks fit the budget."""
+    rule = grouped_assign_mod.groups_per_step
+    size = grouped_assign_mod._group_block_bytes
+    budget = grouped_assign_mod.GROUP_VMEM_BUDGET
+    assert rule(102, 72, 128, 256) == 102
+    gs = rule(1638, 72, 128, 256)
+    assert gs < 1638 and gs % 8 == 0
+    assert size(gs, 72, 128, 256) <= budget < size(gs + 8, 72, 128, 256)
+    assert budget < grouped_assign_mod.VMEM_LIMIT
 
 
 def test_group_block_mask_construction():

@@ -12,6 +12,7 @@ xdist worker imports every test file. The persistent compilation cache
 is off around these compiles (an entry written for a described chip
 cannot be read back without one).
 """
+import importlib
 import os
 
 import jax
@@ -26,6 +27,8 @@ from repro.core import engine as _engine
 from repro.core.distributed import make_fit_sharded_engine
 from repro.kernels import grouped_assign
 
+# the module, which the package's function of the same name shadows
+grouped_assign_mod = importlib.import_module("repro.kernels.grouped_assign")
 LMAX = 24          # the largest centroid group the shapes below assume
 
 
@@ -76,6 +79,30 @@ def test_grouped_assign_compiles_for_v5e(one_chip, name):
         s((g, LMAX), jnp.int32), s((n // tile_n, g), jnp.bool_),
         s((n,), jnp.float32), s((g, LMAX), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n,d,g,lmax,all_groups", [
+    (262_144, 128, 102, 72, True),     # the IVF1024 cell
+    (32_768, 128, 1_638, 72, False),   # K=16,384
+])
+def test_grouped_assign_group_loop_compiles_for_v5e(one_chip, n, d, g, lmax,
+                                                    all_groups):
+    """The kernel's in-step group loop with its resident centroid blocks
+    at the size the groups-per-step rule gives: within the kernel's VMEM
+    limit, and the whole program within one chip's 16 GB."""
+    gs = grouped_assign_mod.groups_per_step(g, lmax, d, 256)
+    assert (gs == g) is all_groups
+    s = _on(one_chip)
+    fn = jax.jit(lambda x, c, ids, m, x2, c2: grouped_assign(
+        x, c, ids, m, tile_n=256, interpret=False, x2=x2, c2g=c2))
+    compiled = fn.lower(
+        s((n, d), jnp.float32), s((g, lmax, d), jnp.float32),
+        s((g, lmax), jnp.int32), s((n // 256, g), jnp.bool_),
+        s((n,), jnp.float32), s((g, lmax), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 << 30
 
 
 def test_pallas_candidate_pass_compiles_for_v5e(one_chip):
