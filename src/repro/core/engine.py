@@ -372,7 +372,8 @@ def move_and_bounds(points, centroids, assignments, ub, lb, groups,
 
     Returns a :class:`MoveOut`.
     """
-    sums, bcounts = centroid_sums(points, assignments, k, weights=weights)
+    with jax.named_scope("kpynq/centroid_sums"):
+        sums, bcounts = centroid_sums(points, assignments, k, weights=weights)
     with jax.named_scope("kpynq/reduce"):
         sums = reducer.sums(sums)
         bcounts = reducer.add(bcounts)

@@ -6,6 +6,9 @@ backend must land on Lloyd's fixed point — same assignments, same
 inertia — across ragged shapes, single-group (Hamerly) runs, and
 iterations where every candidate is filtered out.
 """
+import importlib.util
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -267,3 +270,45 @@ def test_ladder_candidate_pass_matches_fixed_cap():
             for a, b in zip(ref, out):
                 np.testing.assert_array_equal(np.asarray(a),
                                               np.asarray(b))
+
+
+def _plain_reference():
+    """``bench/reference.py``: plain Lloyd at ``HIGHEST`` and its squared
+    distances, written out without ``repro.core``."""
+    path = pathlib.Path(__file__).resolve().parents[1] / "bench" / "reference.py"
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("eng,backend", [("pallas", "pallas"),
+                                         ("auto", "compact")])
+def test_census_shape_matches_plain_lloyd(eng, backend):
+    """census1990-d68-k50's shape class at a CPU size: N = 9,645 (ragged
+    against the 256-point tile), D = 68, K = 50 in G = 5 groups,
+    overlapping blobs (spread 1) and 20 fixed iterations, fitted through
+    ``KMeans`` on the interpreted Pallas kernel and on the ``auto``
+    path (the compact backend on the CPU), against plain Lloyd from the
+    same k-means++ seeds."""
+    n, d, k, g, iters = 9_645, 68, 50, 5, 20
+    pts = jnp.asarray(make_points(n, d, k, seed=0, spread=1.0)[0])
+    init = kmeans_plusplus(jax.random.PRNGKey(0), pts, k)
+    ref = _plain_reference()
+    c_ref, a_ref, _ = ref.lloyd(pts, init, max_iters=iters, tol=-1.0)
+    d2 = ref.sq_dists(pts, c_ref)
+    km = KMeans(n_clusters=k, n_groups=g, engine=eng, max_iters=iters,
+                tol=-1.0, seed=0).fit(pts)
+    assert km.stats_.backend == backend and km.n_iter_ == iters
+    # A label may differ from the reference's only at a near-tie: the two
+    # centroids' squared distances equal within float32 rounding of the
+    # ~10^2-sized terms summed in another order (relative 1e-5).
+    labels, a_ref, d2 = (np.asarray(v) for v in (km.labels_, a_ref, d2))
+    off = np.nonzero(labels != a_ref)[0]
+    own, best = d2[off, labels[off]], d2[off, a_ref[off]]
+    assert np.all(np.abs(own - best) <= 1e-5 * best)
+    # Equal labels give equal means up to the order of a float32 sum over
+    # ~190 points of norm ~10: 1e-5 absolute. No near-tie flipped here, so
+    # a flip (which moves two centroids by ~0.04) fails this too.
+    np.testing.assert_allclose(np.asarray(km.cluster_centers_),
+                               np.asarray(c_ref), rtol=0, atol=1e-5)

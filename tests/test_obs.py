@@ -316,6 +316,20 @@ def test_kmeans_fit_bit_identical_under_the_profiler(tmp_path):
                                   np.asarray(traced.labels_))
 
 
+def test_move_and_bounds_scopes_the_centroid_sums():
+    """The scatter-adds of the centroid sums are traced under
+    ``kpynq/centroid_sums``, which ``centroid_sums_ms.fit`` reads; the
+    rest of the move (drift, bounds) is not."""
+    n, d, k, g = 64, 4, 6, 2
+    fn = jax.jit(lambda *a: engine.move_and_bounds(*a, k=k, n_groups=g))
+    text = fn.lower(jnp.ones((n, d)), jnp.ones((k, d)),
+                    jnp.zeros(n, jnp.int32), jnp.ones(n), jnp.ones((n, g)),
+                    jnp.zeros(k, jnp.int32)).as_text(debug_info=True)
+    assert "kpynq/centroid_sums/scatter-add" in text
+    assert "kpynq/centroid_sums/reduce_max" not in text
+    assert "/reduce_max" in text                # the group drift, outside
+
+
 def test_compile_count_counts_a_fresh_jit_once():
     f = jax.jit(lambda x: x * 3.0 + 1.0)
     x = jnp.arange(7, dtype=jnp.float32).block_until_ready()

@@ -84,6 +84,7 @@ def test_grouped_assign_compiles_for_v5e(one_chip, name):
 @pytest.mark.parametrize("n,d,g,lmax,all_groups", [
     (262_144, 128, 102, 72, True),     # the IVF1024 cell
     (32_768, 128, 1_638, 72, False),   # K=16,384
+    (2_458_285, 68, 5, LMAX, True),    # census1990-d68-k50: ragged N, D=68
 ])
 def test_grouped_assign_group_loop_compiles_for_v5e(one_chip, n, d, g, lmax,
                                                     all_groups):
@@ -97,7 +98,7 @@ def test_grouped_assign_group_loop_compiles_for_v5e(one_chip, n, d, g, lmax,
         x, c, ids, m, tile_n=256, interpret=False, x2=x2, c2g=c2))
     compiled = fn.lower(
         s((n, d), jnp.float32), s((g, lmax, d), jnp.float32),
-        s((g, lmax), jnp.int32), s((n // 256, g), jnp.bool_),
+        s((g, lmax), jnp.int32), s((-(-n // 256), g), jnp.bool_),
         s((n,), jnp.float32), s((g, lmax), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -122,6 +123,29 @@ def test_pallas_candidate_pass_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     # the (N, G) bounds and the kernel's outputs fit one 16 GB chip
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_census_candidate_pass_compiles_for_v5e(one_chip):
+    """The engine's pallas candidate pass at census1990-d68-k50's shape:
+    N = 2,458,285, 173 past a multiple of the tile (the points, their
+    norms and the block mask are padded in every pass), D = 68 off the
+    128 lanes, K = 50 in G = 5 groups; arguments, outputs and temporaries
+    within one 16 GB chip."""
+    n, d, k, g = 2_458_285, 68, 50, 5
+    s = _on(one_chip)
+    fn = jax.jit(lambda *a: _engine.pallas_candidate_pass(
+        *a[:9], n_groups=g, tile_n=256, interpret=False, x2=a[9],
+        c2=a[10]))
+    compiled = fn.lower(
+        s((n, d), jnp.float32), s((k, d), jnp.float32),
+        s((n,), jnp.int32), s((n,), jnp.float32), s((n, g), jnp.float32),
+        s((k,), jnp.int32), s((g, LMAX), jnp.int32), s((g,), jnp.float32),
+        s((n,), jnp.bool_), s((n,), jnp.float32),
+        s((k,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 << 30
 
 
 def test_serve_fused_assign_compiles_for_v5e(one_chip):
