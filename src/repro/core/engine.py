@@ -117,7 +117,7 @@ from .distances import (CROSS_PRECISION, pairwise_dists,
                         pairwise_sq_dists, row_norms_sq, rowwise_dists)
 from .kmeans import (EvalCount, KMeansResult, _init_filter_state,
                      centroid_sums, centroids_from_sums, group_centroids,
-                     lloyd)
+                     lloyd, onehot_centroid_sums)
 
 BACKENDS = ("oracle", "compact", "pallas")
 
@@ -212,6 +212,29 @@ def use_groups_decision(*, cap_n: int, cap_g: int, l_max: int, k: int,
     single copy of the rule, shared by the pass (trace-time), the
     driver (per-segment stats), and the tuner (search space)."""
     return (cap_g * l_max * group_gather_factor <= k) and cap_n <= chunk
+
+
+# The one-hot product's MXU work per point grows with K*D; the
+# scatter-adds' cost ~14-17 ns a point whatever K. Timed on one TPU v5
+# lite, scatter-adds against the product at HIGHEST (ms a call): N =
+# 2,458,285, D = 68, K = 50: 41.2 against 1.32; N = 262,144, D = 128,
+# K = 256: 4.10 / 0.68, K = 1,024: 4.11 / 1.48, K = 2,048: 3.56 / 2.83,
+# K = 4,096: 3.68 / 5.50, K = 16,384: 3.70 / 23.8. The product takes
+# every K*D up to 2,048 x 128. (Three bf16 parts of the points in three
+# bf16 products, exact too, were slower at every shape: 3.35 ms at
+# census, 1.82 at K = 1,024.)
+ONEHOT_SUMS_MAX_KD = 2_048 * 128
+
+
+def onehot_sums(k: int, d: int, *, platform: str,
+                weighted: bool = False) -> bool:
+    """Whether :func:`move_and_bounds` computes the centroid sums as the
+    one-hot MXU product (:func:`~repro.core.kmeans.onehot_centroid_sums`)
+    rather than the scatter-adds of
+    :func:`~repro.core.kmeans.centroid_sums`: on the TPU, unweighted,
+    and at a per-point product work ``k * d`` the MXU wins at."""
+    return platform == "tpu" and not weighted and \
+        k * d <= ONEHOT_SUMS_MAX_KD
 
 
 # --------------------------------------------------------------------------
@@ -336,7 +359,8 @@ def move_and_bounds(points, centroids, assignments, ub, lb, groups,
                     *, k: int, n_groups: int,
                     reducer: Reducer = LOCAL_REDUCER,
                     update=CONVERGENCE_UPDATE, counts=None, decay=None,
-                    weights=None, x2=None, refresh: bool = True):
+                    weights=None, x2=None, refresh: bool = True,
+                    onehot: bool = False):
     """Centroid move + triangle-inequality bound maintenance + the
     point-level filter — the pass core's move half, shared VERBATIM by
     every driver (batch, sharded, streaming).
@@ -370,10 +394,17 @@ def move_and_bounds(points, centroids, assignments, ub, lb, groups,
     (``refresh_ub=True``), so the full-N gather + rowwise pass
     disappears from the hot loop.
 
+    ``onehot``: compute the centroid sums as the one-hot MXU product
+    (see :func:`onehot_sums`, which decides it; unweighted only).
+
     Returns a :class:`MoveOut`.
     """
     with jax.named_scope("kpynq/centroid_sums"):
-        sums, bcounts = centroid_sums(points, assignments, k, weights=weights)
+        if onehot:
+            sums, bcounts = onehot_centroid_sums(points, assignments, k)
+        else:
+            sums, bcounts = centroid_sums(points, assignments, k,
+                                          weights=weights)
     with jax.named_scope("kpynq/reduce"):
         sums = reducer.sums(sums)
         bcounts = reducer.add(bcounts)
@@ -821,7 +852,9 @@ class EngineStats:
     backend and on the TPU); ``compiles`` the programs lowered during
     the fit (:func:`repro.obs.compile_count`; through ``KMeans.fit``,
     its seeding included): each a compile or a persistent-cache load,
-    so a non-zero count on a repeated fit of one shape is a recompile.
+    so a non-zero count on a repeated fit of one shape is a recompile;
+    ``onehot_sums`` whether the fit's centroid sums ran as the one-hot
+    MXU product (:func:`onehot_sums`) rather than the scatter-adds.
 
     With observability enabled (``fit(obs=...)``) the stats carry the
     drained telemetry ring: ``ring`` is the trimmed
@@ -848,6 +881,7 @@ class EngineStats:
     shard_skew: np.ndarray | None = None
     interpret: bool = False
     compiles: int = 0
+    onehot_sums: bool = False
 
     def telemetry(self) -> dict | None:
         """Headline ring summary (iters, mean candidate fraction, total
@@ -877,6 +911,7 @@ class EngineStats:
             "n_points": int(self.n_points),
             "interpret": bool(self.interpret),
             "compiles": int(self.compiles),
+            "onehot_sums": bool(self.onehot_sums),
         }
         if self.ring is not None:
             out["ring_columns"] = list(self.ring_columns)
@@ -934,6 +969,9 @@ class PassCore:
     # emit each ring row as it is written via io_callback (see
     # repro.obs.ring.add_ring_listener); requires ring_iters > 0
     live_drain: bool = False
+    # centroid sums as the one-hot MXU product (see onehot_sums; the
+    # batch fit resolves it, every other driver keeps the scatter)
+    onehot_sums: bool = False
 
     @classmethod
     def from_config(cls, cfg: EngineConfig, *, backend: str, k: int,
@@ -1024,7 +1062,8 @@ def _loop_body(core: PassCore, points, weights, groups, members, gsize):
             mv = move_and_bounds(
                 points, c.centroids, new_as, new_ub, new_lb, groups,
                 k=core.k, n_groups=core.n_groups, reducer=core.reducer,
-                weights=weights, x2=c.x2, refresh=core.refresh_in_move)
+                weights=weights, x2=c.x2, refresh=core.refresh_in_move,
+                onehot=core.onehot_sums)
         n_cand = jnp.sum(mv.need.astype(jnp.int32))
         ring = c.ring
         if core.ring_iters:
@@ -1379,6 +1418,7 @@ def _publish_fit(obs_cfg, stats: EngineStats, result) -> None:
                 labels=labels).inc(float(stats.compiles))
     evt = {"backend": stats.backend, "n_iters": stats.n_iters,
            "host_syncs": stats.host_syncs, "compiles": stats.compiles,
+           "onehot_sums": stats.onehot_sums,
            "n_points": stats.n_points,
            "distance_evals": float(result.distance_evals),
            "inertia": float(result.inertia)}
@@ -1483,8 +1523,11 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
     n_groups = int(min(n_groups, k))
     tol = float(tol)
 
-    stats = EngineStats(backend=backend, x2_evals=1, config=cfg.to_dict(),
-                        n_points=n, interpret=bool(interpret))
+    stats = EngineStats(
+        backend=backend, x2_evals=1, config=cfg.to_dict(), n_points=n,
+        interpret=bool(interpret),
+        onehot_sums=onehot_sums(k, d, platform=jax.default_backend(),
+                                weighted=weights is not None))
     cap_floor = min(cfg.min_cap, n)
 
     def _core(cap_n, cap_g, l_max):
@@ -1495,7 +1538,8 @@ def fit(points, init_centroids, *, n_groups: int | None = None,
         return PassCore.from_config(
             cfg, backend=backend, k=k, n_groups=n_groups, cap_n=cap_n,
             cap_g=cap_g, use_groups=ug, interpret=bool(interpret),
-            ring_iters=ring_iters, live_drain=live_drain)
+            ring_iters=ring_iters, live_drain=live_drain,
+            onehot_sums=stats.onehot_sums)
 
     def _drain_ring(ring):
         # one device_get at fit exit — rides the exit fetch the driver

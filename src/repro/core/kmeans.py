@@ -56,6 +56,25 @@ def centroid_sums(points, assignments, k, weights=None):
     return sums, counts
 
 
+def onehot_centroid_sums(points, assignments, k):
+    """:func:`centroid_sums` (unweighted) as one matrix product,
+    ``onehot(assignments)^T @ points``, for the MXU, where the
+    scatter-adds of ``segment_sum`` go one point at a time.
+
+    The one-hot is built inside the product's operand, so XLA fuses it:
+    the points and labels are read once, and no (N, K) array is
+    stored. At ``HIGHEST`` every product of a coordinate is exact (the
+    0/1 side is exact in bf16, so every term the bf16 passes drop
+    multiplies a zero) and every sum accumulates in float32. The counts
+    are the one-hot's column sums, exact below 2^24 points."""
+    onehot = assignments[:, None] == jnp.arange(k, dtype=assignments.dtype)
+    sums = jnp.einsum("nk,nd->kd", onehot.astype(jnp.float32),
+                      points.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    return sums, jnp.sum(onehot, axis=0, dtype=jnp.float32)
+
+
 def centroids_from_sums(sums, counts, prev_centroids):
     """Divide reduced sums by counts. Empty clusters keep their previous
     centroid (standard practice; also what keeps the filtered and
@@ -67,8 +86,10 @@ def centroids_from_sums(sums, counts, prev_centroids):
 def update_centroids(points, assignments, k, prev_centroids,
                      weights=None):
     """Segment-sum centroid update — O(N*D), the right formulation for
-    CPU/scatter hardware. (The TPU path uses the one-hot MXU matmul in
-    kernels/centroid_update.py instead; same math.)
+    CPU/scatter hardware. (On the TPU, the batch fit's
+    ``engine.move_and_bounds`` takes the sums from
+    :func:`onehot_centroid_sums` instead, where ``engine.onehot_sums``
+    picks it; same math.)
     """
     sums, counts = centroid_sums(points, assignments, k, weights=weights)
     return centroids_from_sums(sums, counts, prev_centroids), counts

@@ -312,3 +312,45 @@ def test_census_shape_matches_plain_lloyd(eng, backend):
     # a flip (which moves two centroids by ~0.04) fails this too.
     np.testing.assert_allclose(np.asarray(km.cluster_centers_),
                                np.asarray(c_ref), rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("k,d,platform,weighted,want", [
+    (50, 68, "tpu", False, True),          # census1990-d68-k50
+    (1_024, 128, "tpu", False, True),      # sift128-ivf1024
+    (2_048, 128, "tpu", False, True),      # the largest K*D timed a win
+    (4_096, 128, "tpu", False, False),     # timed a loss
+    (16_384, 128, "tpu", False, False),    # N*K*D of MXU work loses
+    (50, 68, "tpu", True, False),          # weighted sums keep the scatter
+    (50, 68, "cpu", False, False),
+    (1_024, 128, "cpu", False, False),
+])
+def test_onehot_sums_rule(k, d, platform, weighted, want):
+    assert engine.onehot_sums(k, d, platform=platform,
+                              weighted=weighted) is want
+
+
+def test_census_shape_onehot_sums_match_plain_lloyd(monkeypatch):
+    """The batch fit with the one-hot product forced on (the rule takes
+    it on the TPU only) at census1990-d68-k50's CPU shape, against plain
+    Lloyd from the same k-means++ seeds; the stats say which path ran."""
+    n, d, k, g, iters = 9_645, 68, 50, 5, 20
+    pts = jnp.asarray(make_points(n, d, k, seed=0, spread=1.0)[0])
+    init = kmeans_plusplus(jax.random.PRNGKey(0), pts, k)
+    ref = _plain_reference()
+    c_ref, a_ref, _ = ref.lloyd(pts, init, max_iters=iters, tol=-1.0)
+    d2 = np.asarray(ref.sq_dists(pts, c_ref))
+    km = KMeans(n_clusters=k, n_groups=g, engine="auto", max_iters=iters,
+                tol=-1.0, seed=0).fit(pts)
+    assert not km.stats_.onehot_sums
+    monkeypatch.setattr(engine, "onehot_sums", lambda *a, **kw: True)
+    km = KMeans(n_clusters=k, n_groups=g, engine="auto", max_iters=iters,
+                tol=-1.0, seed=0).fit(pts)
+    assert km.stats_.onehot_sums and km.stats_.to_dict()["onehot_sums"]
+    assert km.n_iter_ == iters
+    # the same near-tie and centroid tolerances as the scatter's fit above
+    labels, a_ref = np.asarray(km.labels_), np.asarray(a_ref)
+    off = np.nonzero(labels != a_ref)[0]
+    own, best = d2[off, labels[off]], d2[off, a_ref[off]]
+    assert np.all(np.abs(own - best) <= 1e-5 * best)
+    np.testing.assert_allclose(np.asarray(km.cluster_centers_),
+                               np.asarray(c_ref), rtol=0, atol=1e-5)

@@ -12,6 +12,7 @@ import pytest
 
 from repro.core import (KMeans, group_centroids, kmeans_plusplus, lloyd,
                         random_init, yinyang)
+from repro.core.kmeans import onehot_centroid_sums
 from repro.data import make_points
 
 
@@ -174,3 +175,29 @@ def test_compact_path_matches_lloyd():
              np.asarray(r_c.assignments)).mean()
     assert agree > 0.999  # fp-tie divergence only
     assert float(r_c.distance_evals) < float(r_l.distance_evals)
+
+
+@pytest.mark.parametrize("n,d,k,labels", [
+    (9_645, 68, 50, "spread"),        # census width, N 173 past 256 * 37
+    (9_645, 128, 1_024, "spread"),    # sift width at IVF1024
+    (9_645, 68, 50, "one"),           # every point in one cluster
+    (2_048, 128, 1_024, "one"),
+])
+def test_onehot_centroid_sums_match_float64(n, d, k, labels):
+    """The one-hot product's sums against float64 numpy: within a float32
+    accumulation bound (16 ulps of the summed magnitudes), counts exact."""
+    rng = np.random.default_rng(n + d + k)
+    x = (rng.normal(size=(n, d)) * 3.0 + 5.0).astype(np.float32)
+    a = rng.integers(0, k, n) if labels == "spread" else np.full(n, k - 1)
+    a = a.astype(np.int32)
+    sums, counts = jax.jit(onehot_centroid_sums, static_argnums=2)(
+        jnp.asarray(x), jnp.asarray(a), k)
+    ref = np.zeros((k, d))
+    np.add.at(ref, a, x.astype(np.float64))
+    mag = np.zeros((k, d))
+    np.add.at(mag, a, np.abs(x.astype(np.float64)))
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.bincount(a, minlength=k))
+    err = np.abs(np.asarray(sums, np.float64) - ref)
+    assert np.all(err <= 16 * np.finfo(np.float32).eps * mag)
+

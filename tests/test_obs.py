@@ -330,6 +330,37 @@ def test_move_and_bounds_scopes_the_centroid_sums():
     assert "/reduce_max" in text                # the group drift, outside
 
 
+def test_move_and_bounds_scopes_the_onehot_sums():
+    """With ``onehot`` the sums' product and the counts' column sum are
+    traced under ``kpynq/centroid_sums`` too, and no scatter-add is left
+    there."""
+    n, d, k, g = 64, 4, 6, 2
+    fn = jax.jit(lambda *a: engine.move_and_bounds(*a, k=k, n_groups=g,
+                                                   onehot=True))
+    text = fn.lower(jnp.ones((n, d)), jnp.ones((k, d)),
+                    jnp.zeros(n, jnp.int32), jnp.ones(n), jnp.ones((n, g)),
+                    jnp.zeros(k, jnp.int32)).as_text(debug_info=True)
+    assert "kpynq/centroid_sums/nk,nd->kd/dot_general" in text
+    assert "kpynq/centroid_sums/reduce_sum" in text
+    assert "kpynq/centroid_sums/scatter-add" not in text
+
+
+def test_onehot_sums_reported_in_stats_and_event(monkeypatch):
+    """``EngineStats.onehot_sums`` and the ``engine_fit`` event say which
+    sums a fit ran: the scatter on the CPU, the product where the rule
+    takes it."""
+    pts, _ = _dataset()
+    for forced in (False, True):
+        if forced:
+            monkeypatch.setattr(engine, "onehot_sums", lambda *a, **kw: True)
+        reg = MetricsRegistry()
+        km = KMeans(12, engine="compact", max_iters=25, tune="off",
+                    obs=reg).fit(pts)
+        evt, = [e for e in reg.events if e["event"] == "engine_fit"]
+        assert km.stats_.onehot_sums is forced
+        assert evt["onehot_sums"] is forced
+
+
 def test_compile_count_counts_a_fresh_jit_once():
     f = jax.jit(lambda x: x * 3.0 + 1.0)
     x = jnp.arange(7, dtype=jnp.float32).block_until_ready()
