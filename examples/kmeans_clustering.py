@@ -23,9 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.kpynq import paper_suite
-from repro.core import (distributed_yinyang, kmeans_plusplus, lloyd,
-                        yinyang, yinyang_compact)
+from repro.core import (distributed_yinyang, engine_fit, kmeans_plusplus,
+                        lloyd, yinyang)
 from repro.data import PointStream, make_points
+from repro.obs import ObsConfig, format_ring_table
 from repro.streaming import StreamingKMeans
 
 
@@ -51,25 +52,20 @@ def main():
         print(f"{prob.name:12s} {n:9d} {prob.n_dims:4d} {prob.k:5d} "
               f"{int(r_y.n_iters):5d} {wr:8.1f}x {wh:7.1f}x")
 
-    # compaction mode (real wall-clock saving on CPU)
+    # compaction mode: the engine's ``compact`` backend, the whole fit
+    # in one device-resident loop, with the telemetry ring on — the
+    # device records per-iteration filter efficiency (candidates
+    # surviving, evals spent, active capacity bucket, drift) with ZERO
+    # extra host syncs, drained once at exit. Results are bit-identical
+    # with the ring on or off.
     pts_np, _, _ = make_points(32768, 32, 256, seed=0)
     pts = jnp.asarray(pts_np)
     init = kmeans_plusplus(jax.random.PRNGKey(1), pts, 256)
-    r_c = yinyang_compact(pts, init, max_iters=40)
+    r_c, stats = engine_fit(pts, init, max_iters=40, backend="compact",
+                            tune="off", return_stats=True, obs=ObsConfig())
     print(f"\ncompaction mode: iters={int(r_c.n_iters)} "
           f"evals={float(r_c.distance_evals):.3g} "
           f"inertia={float(r_c.inertia):.1f}")
-
-    # observability: the same problem through the engine with the
-    # telemetry ring on — the device records per-iteration filter
-    # efficiency (candidates surviving, evals spent, active capacity
-    # bucket, drift) with ZERO extra host syncs, drained once at exit.
-    # Results are bit-identical with the ring on or off.
-    from repro.core import engine_fit
-    from repro.obs import ObsConfig, format_ring_table
-    _, stats = engine_fit(pts, init, max_iters=40, backend="compact",
-                          tune="off", return_stats=True,
-                          obs=ObsConfig())
     print("\nper-iteration filter efficiency (telemetry ring):")
     print(format_ring_table(stats.ring, stats.n_points, max_rows=12))
     print(f"telemetry: {stats.telemetry()}")

@@ -31,7 +31,7 @@ The iteration loop realises BOTH filter levels as skipped work:
 * the whole fit runs under ``lax.while_loop`` — zero host round-trips
   per iteration. The only host syncs are capacity-bucket transitions
   (O(log N) of them, counted in :class:`EngineStats`), not one per
-  iteration like the legacy ``yinyang_compact`` driver;
+  iteration;
 * **point-level compaction**: surviving points are stream-compacted
   into a padded buffer whose capacity comes from a fixed power-of-two
   lattice, so XLA compiles a small, bounded set of programs;
@@ -122,12 +122,12 @@ from .kmeans import (EvalCount, KMeansResult, _init_filter_state,
 BACKENDS = ("oracle", "compact", "pallas")
 
 # Default backend="auto" work crossover: problems with n*k at or below
-# this route straight to the reference Lloyd loop — BENCH_kmeans.json
-# shows the dense (N, K) GEMM beating the filtered engine at uci-small
-# scale, where one fused matmul per iteration is cheaper than any bound
-# bookkeeping. The fixed point is identical (tests/test_engine.py
-# parity matrix), only distance_evals differ. The per-signature tuned
-# value lives in EngineConfig.lloyd_max_work.
+# this route straight to the reference Lloyd loop. The reason is a CPU
+# measurement at uci-small scale, where one dense (N, K) GEMM per
+# iteration beat the filtered engine's bound bookkeeping; no chip run
+# has measured the crossover. The fixed point is identical
+# (tests/test_engine.py parity matrix), only distance_evals differ. The
+# per-signature tuned value lives in EngineConfig.lloyd_max_work.
 AUTO_LLOYD_MAX_WORK = 1 << 17
 
 # jit-cached Lloyd for the tiny-problem route: calling the bare
@@ -352,7 +352,7 @@ class MoveOut(NamedTuple):
 
 
 # --------------------------------------------------------------------------
-# shared per-iteration pieces (also consumed by compact.py / distributed.py)
+# shared per-iteration pieces (every driver's PassCore runs these)
 # --------------------------------------------------------------------------
 
 def move_and_bounds(points, centroids, assignments, ub, lb, groups,

@@ -1,9 +1,9 @@
 """Pallas TPU kernel: group-granular block-skip nearest-centroid search.
 
-The original ``filtered_assign`` kernel skips (tile_n x tile_k) blocks
-but only yields the global (min, argmin) — enough for Hamerly, not for
-Yinyang, whose lower-bound refresh needs *per-group* minima. This
-kernel makes the centroid block THE GROUP: a (point tile, group) block
+A kernel that skips (tile_n x tile_k) centroid blocks yields only the
+global (min, argmin) — enough for Hamerly, not for Yinyang, whose
+lower-bound refresh needs *per-group* minima. This kernel makes the
+centroid block THE GROUP: a (point tile, group) block
 is exactly one group-level filter decision, and a skipped block is
 skipped MXU work.
 

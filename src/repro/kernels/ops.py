@@ -1,10 +1,10 @@
-"""Jit'd public wrappers gluing the Pallas kernels to the algorithm layer.
+"""Jit'd public wrappers gluing the Pallas kernel to the algorithm layer.
 
-``build_block_mask`` converts the algorithmic per-(point, group) filter
-decisions into the block-granular skip mask the fused kernel consumes —
-the exact point where KPynq's per-point pipeline bypass becomes the
-TPU's block bypass. ``compact_indices`` is the beyond-paper stream-
-compaction alternative (gather survivors into dense tiles).
+``build_group_block_mask`` converts the algorithmic per-(point, group)
+filter decisions into the block-granular skip mask ``grouped_assign``
+consumes — the exact point where KPynq's per-point pipeline bypass
+becomes the TPU's block bypass. ``compact_indices`` is the beyond-paper
+stream-compaction alternative (gather survivors into dense tiles).
 """
 from __future__ import annotations
 
@@ -13,33 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .centroid_update import centroid_update
-from .distance import pairwise_sq_dists
-from .filtered_assign import filtered_assign
 from .grouped_assign import grouped_assign
 
-__all__ = ["pairwise_sq_dists", "filtered_assign", "centroid_update",
-           "build_block_mask", "build_group_block_mask", "compact_indices",
-           "filtered_assign_auto", "grouped_assign"]
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "tile_k"))
-def build_block_mask(group_need: jnp.ndarray, groups: jnp.ndarray,
-                     *, tile_n: int, tile_k: int) -> jnp.ndarray:
-    """(N, G) per-point-per-group need + (K,) group ids ->
-    (ceil(N/tile_n), ceil(K/tile_k)) bool block mask.
-
-    block (i, j) is needed iff any point in tile i needs any group that
-    owns a centroid in centroid-block j.
-    """
-    n, _ = group_need.shape
-    k = groups.shape[0]
-    cand = group_need[:, groups]                            # (N, K) bool
-    n_pad, k_pad = (-n) % tile_n, (-k) % tile_k
-    cand = jnp.pad(cand, ((0, n_pad), (0, k_pad)))
-    gn, gk = cand.shape[0] // tile_n, cand.shape[1] // tile_k
-    blocks = cand.reshape(gn, tile_n, gk, tile_k)
-    return jnp.any(blocks, axis=(1, 3))
+__all__ = ["build_group_block_mask", "compact_indices", "grouped_assign"]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n",))
@@ -47,9 +23,8 @@ def build_group_block_mask(group_need: jnp.ndarray, *,
                            tile_n: int) -> jnp.ndarray:
     """(N, G) per-point-per-group need -> (ceil(N/tile_n), G) bool mask
     for the group-granular kernel (``grouped_assign``): block (i, g) is
-    live iff any point in tile i needs group g. Finer-grained than
-    ``build_block_mask`` — a group IS a centroid block, so the
-    group-level filter maps 1:1 onto skipped blocks."""
+    live iff any point in tile i needs group g. A group IS a centroid
+    block, so the group-level filter maps 1:1 onto skipped blocks."""
     n, g = group_need.shape
     n_pad = (-n) % tile_n
     padded = jnp.pad(group_need, ((0, n_pad), (0, 0)))
@@ -71,16 +46,3 @@ def compact_indices(mask: jnp.ndarray, *, capacity: int):
     idx = jnp.zeros((capacity,), jnp.int32).at[slot].set(src, mode="drop")
     valid = jnp.arange(capacity) < jnp.minimum(count, capacity)
     return idx, valid, count
-
-
-def filtered_assign_auto(x, c, group_need, groups, *,
-                         tile_n: int = 256, tile_k: int = 128,
-                         interpret: bool = False):
-    """One call: algorithmic filter decisions -> block mask -> fused
-    block-skip kernel. Returns (min_sq_dist, argmin, block_density)."""
-    mask = build_block_mask(group_need, groups, tile_n=tile_n,
-                            tile_k=tile_k)
-    best, idx = filtered_assign(x, c, mask, tile_n=tile_n, tile_k=tile_k,
-                                interpret=interpret)
-    density = jnp.mean(mask.astype(jnp.float32))
-    return best, idx, density

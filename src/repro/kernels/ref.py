@@ -1,8 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
+"""Pure-jnp oracles (the correctness ground truth).
 
-Each function mirrors one kernel in this package with identical
-signature and output semantics; tests sweep shapes/dtypes and
-``assert_allclose`` kernel-vs-oracle.
+Each function mirrors one kernel in this package, or the core
+primitive every driver calls (``pairwise_sq_dists_ref`` for
+``core.distances.pairwise_sq_dists``, ``centroid_update_ref`` for
+``core.kmeans.centroid_sums`` / ``onehot_centroid_sums``), with the
+same output semantics; tests sweep shapes/dtypes and
+``assert_allclose`` implementation-vs-oracle.
 """
 from __future__ import annotations
 
@@ -17,27 +20,6 @@ def pairwise_sq_dists_ref(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
     c2 = jnp.sum(cf * cf, axis=-1)
     return jnp.maximum(x2 - 2.0 * (xf @ cf.T) + c2[None, :], 0.0)
-
-
-def filtered_assign_ref(x: jnp.ndarray, c: jnp.ndarray,
-                        block_mask: jnp.ndarray,
-                        tile_n: int, tile_k: int):
-    """Block-skip argmin oracle.
-
-    ``block_mask[i, j]`` (bool) says whether the distance block
-    (points i*tile_n:(i+1)*tile_n) x (centroids j*tile_k:(j+1)*tile_k)
-    must be computed. Skipped blocks contribute +inf.
-    Returns (min_sq_dist (N,), argmin (N,) int32); rows whose every
-    block is skipped return (+inf, -1).
-    """
-    n, k = x.shape[0], c.shape[0]
-    d2 = pairwise_sq_dists_ref(x, c)
-    mask_full = jnp.repeat(jnp.repeat(block_mask, tile_n, axis=0),
-                           tile_k, axis=1)[:n, :k]
-    d2 = jnp.where(mask_full, d2, jnp.inf)
-    best = jnp.min(d2, axis=1)
-    idx = jnp.where(jnp.isfinite(best), jnp.argmin(d2, axis=1), -1)
-    return best, idx.astype(jnp.int32)
 
 
 def grouped_assign_ref(x, c_grouped, ids, block_mask, tile_n: int):

@@ -6,8 +6,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 For every (arch × shape × mesh) cell: AOT ``jax.jit(...).lower(...)``
 with explicit in/out shardings, ``.compile()``, then record
 memory_analysis / cost_analysis / collective-bytes into a JSON cache
-(results/dryrun/<arch>__<shape>__<mesh>.json). The JSON cache is what
-benchmarks/roofline_report.py and EXPERIMENTS.md read.
+(results/dryrun/<arch>__<shape>__<mesh>.json).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all \

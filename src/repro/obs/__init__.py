@@ -20,7 +20,7 @@ engine can import it without cycles.
 """
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       ObsConfig, default_registry, normalize_obs,
-                      provenance, reset_default_registry)
+                      reset_default_registry)
 from .ring import (N_COUNTERS, RING_COLUMNS, add_ring_listener,
                    caps_from_ring, format_ring_table, reduce_shard_rings,
                    remove_ring_listener, shard_skew, summarize_ring)
@@ -28,8 +28,7 @@ from .trace import compile_count, profile, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "ObsConfig",
-    "default_registry", "normalize_obs", "provenance",
-    "reset_default_registry",
+    "default_registry", "normalize_obs", "reset_default_registry",
     "N_COUNTERS", "RING_COLUMNS", "add_ring_listener", "caps_from_ring",
     "format_ring_table", "reduce_shard_rings", "remove_ring_listener",
     "shard_skew", "summarize_ring",

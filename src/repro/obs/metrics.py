@@ -302,39 +302,3 @@ def normalize_obs(obs) -> ObsConfig | None:
         return obs
     raise TypeError(f"obs must be None, bool, MetricsRegistry or "
                     f"ObsConfig, got {type(obs).__name__}")
-
-
-# --------------------------------------------------------------------------
-# provenance (stamped into BENCH_kmeans.json by the benchmark harness)
-# --------------------------------------------------------------------------
-
-def provenance() -> dict:
-    """Attribution block for benchmark records: git sha, jax version,
-    platform, device count, timestamp. Every field degrades gracefully
-    (no git / no jax initialised -> placeholders), so stamping can
-    never fail a benchmark run."""
-    rec = {"timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
-           "git_sha": "unknown", "jax_version": "unknown",
-           "platform": "unknown", "device_count": 0}
-    try:
-        import subprocess
-        sha = subprocess.run(
-            ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
-            timeout=10)
-        if sha.returncode == 0:
-            rec["git_sha"] = sha.stdout.strip()
-        dirty = subprocess.run(
-            ["git", "status", "--porcelain"], capture_output=True,
-            text=True, timeout=10)
-        if dirty.returncode == 0:
-            rec["git_dirty"] = bool(dirty.stdout.strip())
-    except Exception:
-        pass
-    try:
-        import jax
-        rec["jax_version"] = jax.__version__
-        rec["platform"] = jax.default_backend()
-        rec["device_count"] = jax.device_count()
-    except Exception:
-        pass
-    return rec

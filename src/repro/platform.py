@@ -8,8 +8,8 @@ accelerator it was not written for.
 
 ``use_compile_cache`` points JAX's persistent compilation cache at a
 fixed directory of the checkout. Entry points call it (``chip_smoke.py``,
-``benchmarks/run.py``, the benchmark mains); importing ``repro`` never
-does, and neither do the tests.
+``bench/run.py``); importing ``repro`` never does, and neither do the
+tests.
 """
 from __future__ import annotations
 

@@ -7,8 +7,8 @@ override with the ``REPRO_KMEANS_TUNE_CACHE`` environment variable or
 an explicit ``TuneCache(path=...)``.
 
 The cache is loaded once per process and written through on every
-store, so ``benchmarks/run.py --tune`` and the fits that follow in the
-same process always agree. A corrupt or version-mismatched file is
+store, so a ``fit(tune="force")`` search and the fits that follow in
+the same process always agree. A corrupt or version-mismatched file is
 treated as empty (tuning is always safe to redo — it can never change
 results, only wall-clock).
 """

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (KMeans, NotFittedError, kmeans_plusplus, lloyd,
-                        yinyang_compact)
+from repro.core import KMeans, NotFittedError, kmeans_plusplus, lloyd
 from repro.core import engine
 from repro.data import make_points
 
@@ -130,9 +129,9 @@ def test_engine_auto_backend_resolves():
 
 
 def test_engine_auto_routes_tiny_to_lloyd():
-    """BENCH_kmeans.json: at uci-small scale the dense Lloyd GEMM beats
-    the filtered engine ~3.6x, so 'auto' must route below the n*k
-    threshold — and land on the identical fixed point."""
+    """'auto' routes below the n*k threshold to the dense Lloyd loop
+    (see ``engine.AUTO_LLOYD_MAX_WORK``) — and lands on the identical
+    fixed point."""
     pts, init = _dataset(512, 8, 16)
     assert 512 * 16 <= engine.AUTO_LLOYD_MAX_WORK
     r, stats = engine.fit(pts, init, backend="auto", max_iters=30,
@@ -145,14 +144,6 @@ def test_engine_auto_routes_tiny_to_lloyd():
     _, big_stats = engine.fit(big_pts, big_init, backend="auto",
                               max_iters=10, return_stats=True)
     assert big_stats.backend in ("compact", "pallas")
-
-
-def test_compact_wrapper_delegates_to_engine_math():
-    pts, init = _dataset(4000, 12, 24, seed=7)
-    r_l = lloyd(pts, init, max_iters=40, tol=1e-5)
-    r_c = yinyang_compact(pts, init, max_iters=40, tol=1e-5)
-    np.testing.assert_allclose(float(r_c.inertia), float(r_l.inertia),
-                               rtol=1e-5)
 
 
 def test_not_fitted_error():

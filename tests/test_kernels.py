@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(Pallas interpret=True executes the kernel body on CPU)."""
+(Pallas interpret=True executes the kernel body on CPU), plus the core
+distance and centroid-sum primitives every driver calls."""
 import importlib
 
 import jax
@@ -7,12 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (build_block_mask, build_group_block_mask,
-                           centroid_update, compact_indices,
-                           filtered_assign, filtered_assign_auto,
-                           grouped_assign, pairwise_sq_dists)
-from repro.kernels.ref import (centroid_update_ref, filtered_assign_ref,
-                               grouped_assign_ref, pairwise_sq_dists_ref)
+from repro.core.distances import pairwise_sq_dists
+from repro.core.kmeans import centroid_sums, onehot_centroid_sums
+from repro.kernels import (build_group_block_mask, compact_indices,
+                           grouped_assign)
+from repro.kernels.ref import (centroid_update_ref, grouped_assign_ref,
+                               pairwise_sq_dists_ref)
 
 # the module, which the package's function of the same name shadows
 grouped_assign_mod = importlib.import_module("repro.kernels.grouped_assign")
@@ -29,25 +30,41 @@ def test_pairwise_sq_dists(n, d, k, dtype):
     kx, kc = jax.random.split(jax.random.PRNGKey(n + k))
     x = jax.random.normal(kx, (n, d), dtype)
     c = jax.random.normal(kc, (k, d), dtype)
-    got = pairwise_sq_dists(x, c, interpret=True)
+    got = pairwise_sq_dists(x, c)
     want = pairwise_sq_dists_ref(x, c)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol * 10)
 
 
+def _bucket(c, groups, g, lmax=1):
+    """(K, D) centroids + (K,) group ids -> the kernel's (G, Lmax, D)
+    group buckets and (G, Lmax) id table (-1 = pad)."""
+    groups = np.asarray(groups)
+    lmax = max(int(np.bincount(groups, minlength=g).max()), lmax)
+    members = np.full((g, lmax), -1, np.int32)
+    for gg in range(g):
+        ids = np.nonzero(groups == gg)[0]
+        members[gg, :len(ids)] = ids
+    ids = jnp.asarray(members)
+    return c[jnp.maximum(ids, 0)], ids
+
+
 @pytest.mark.parametrize("n,d,k", SHAPES)
 @pytest.mark.parametrize("density", [0.0, 0.35, 1.0])
-def test_filtered_assign_block_skip(n, d, k, density):
-    tile_n, tile_k = 256, 128
-    kx, kc, km = jax.random.split(jax.random.PRNGKey(n * k + 1), 3)
+def test_grouped_assign_block_skip(n, d, k, density):
+    """A random (tile, group) block mask: live blocks score as the
+    oracle does, and a row with no live group comes back as no
+    candidate (+inf, -1)."""
+    tile_n, g = 256, max(k // 16, 1)
+    kx, kc, kg, km = jax.random.split(jax.random.PRNGKey(n * k + 1), 4)
     x = jax.random.normal(kx, (n, d))
     c = jax.random.normal(kc, (k, d))
-    gn, gk = -(-n // tile_n), -(-k // tile_k)
-    mask = jax.random.bernoulli(km, density, (gn, gk))
-    best, idx = filtered_assign(x, c, mask, tile_n=tile_n, tile_k=tile_k,
-                                interpret=True)
-    bref, iref = filtered_assign_ref(x, c, mask, tile_n, tile_k)
+    c_grouped, ids = _bucket(c, jax.random.randint(kg, (k,), 0, g), g)
+    mask = jax.random.bernoulli(km, density, (-(-n // tile_n), g))
+    best, idx, *_ = grouped_assign(x, c_grouped, ids, mask, tile_n=tile_n,
+                                   interpret=True)
+    bref, iref, *_ = grouped_assign_ref(x, c_grouped, ids, mask, tile_n)
     finite = np.isfinite(np.asarray(bref))
     np.testing.assert_allclose(np.asarray(best)[finite],
                                np.asarray(bref)[finite], rtol=1e-5,
@@ -74,16 +91,10 @@ def test_grouped_assign_matches_ref(monkeypatch, n, d, k, g, tile_n,
     kx, kc, kg, km = jax.random.split(jax.random.PRNGKey(n + k), 4)
     x = jax.random.normal(kx, (n, d))
     c = jax.random.normal(kc, (k, d))
-    groups = np.asarray(jax.random.randint(kg, (k,), 0, g))
-    lmax = max(int(np.bincount(groups, minlength=g).max()), lmax or 1)
-    gs = grouped_assign_mod.groups_per_step(g, lmax, d, tile_n)
+    c_grouped, ids = _bucket(c, jax.random.randint(kg, (k,), 0, g), g,
+                             lmax or 1)
+    gs = grouped_assign_mod.groups_per_step(g, ids.shape[1], d, tile_n)
     assert gs < g if budget is not None else gs == g
-    members = np.full((g, lmax), -1, np.int32)
-    for gg in range(g):
-        ids = np.nonzero(groups == gg)[0]
-        members[gg, :len(ids)] = ids
-    ids = jnp.asarray(members)
-    c_grouped = c[jnp.maximum(ids, 0)]
     gn = -(-n // tile_n)
     if density == "halves":    # even tiles need no group, odd ones all
         mask = jnp.repeat((jnp.arange(gn) % 2 == 1)[:, None], g, axis=1)
@@ -128,39 +139,32 @@ def test_group_block_mask_construction():
 
 
 @pytest.mark.parametrize("n,d,k", SHAPES)
-def test_centroid_update(n, d, k):
+@pytest.mark.parametrize("sums_fn", [centroid_sums, onehot_centroid_sums])
+def test_centroid_sums_match_ref(n, d, k, sums_fn):
     kx, ka = jax.random.split(jax.random.PRNGKey(n + d))
     x = jax.random.normal(kx, (n, d))
     a = jax.random.randint(ka, (n,), 0, k)
-    sums, counts = centroid_update(x, a, k=k, interpret=True)
+    sums, counts = sums_fn(x, a, k)
     sref, cref = centroid_update_ref(x, a, k)
     np.testing.assert_allclose(np.asarray(sums), np.asarray(sref),
                                rtol=1e-5, atol=1e-4)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(cref))
 
 
-def test_block_mask_construction():
-    n, k, g = 600, 96, 4
-    groups = jnp.arange(k) % g
-    need = jnp.zeros((n, g), bool).at[:, 1].set(True)
-    mask = build_block_mask(need, groups, tile_n=256, tile_k=32)
-    # every centroid block containing a group-1 centroid must be live
-    assert mask.shape == (3, 3)
-    assert bool(mask.any())
-
-
 def test_fused_auto_path_equals_bruteforce_when_dense():
+    """Every group needed: the filter's mask, through the kernel, lands
+    on the brute-force argmin."""
     kx, kc = jax.random.split(jax.random.PRNGKey(0))
     x = jax.random.normal(kx, (500, 24))
     c = jax.random.normal(kc, (64, 24))
-    groups = jnp.arange(64) % 4
-    need = jnp.ones((500, 4), bool)
-    best, idx, density = filtered_assign_auto(x, c, need, groups,
-                                              interpret=True)
+    c_grouped, ids = _bucket(c, np.arange(64) % 4, 4)
+    mask = build_group_block_mask(jnp.ones((500, 4), bool), tile_n=256)
+    assert bool(mask.all())
+    _, idx, *_ = grouped_assign(x, c_grouped, ids, mask, tile_n=256,
+                                interpret=True)
     want = pairwise_sq_dists_ref(x, c)
     np.testing.assert_array_equal(np.asarray(idx),
                                   np.asarray(jnp.argmin(want, axis=1)))
-    assert float(density) == 1.0
 
 
 def test_compact_indices_matches_nonzero():
@@ -177,7 +181,7 @@ def test_compact_indices_matches_nonzero():
     (1, 1, 64, 16, 16, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(b, h, s, d, bq, bk, dtype):
-    from repro.kernels import flash_attention
+    from repro.kernels.flash_attention import flash_attention
     from repro.kernels.ref import flash_attention_ref
     key = jax.random.PRNGKey(s + d)
     q, k, v = (jax.random.normal(kk, (b, h, s, d), dtype)
@@ -193,8 +197,8 @@ def test_flash_attention(b, h, s, d, bq, bk, dtype):
 @pytest.mark.parametrize("g,q,n,p_", [(4, 32, 16, 32), (2, 128, 8, 64),
                                       (1, 16, 128, 16)])
 def test_ssd_intra(g, q, n, p_):
-    from repro.kernels import ssd_intra
     from repro.kernels.ref import ssd_intra_ref
+    from repro.kernels.ssd_intra import ssd_intra
     key = jax.random.PRNGKey(g + q)
     kc, kb, kx, kd = jax.random.split(key, 4)
     c = jax.random.normal(kc, (g, q, n))

@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (KMeans, group_centroids, kmeans_plusplus, lloyd,
-                        random_init, yinyang)
+from repro.core import (KMeans, engine, group_centroids, kmeans_plusplus,
+                        lloyd, random_init, yinyang)
 from repro.core.kmeans import onehot_centroid_sums
 from repro.data import make_points
 
@@ -164,11 +164,20 @@ def test_distance_evals_counter_is_precision_safe():
     assert exact == 100 * (2 ** 24 - 1)
 
 
-def test_compact_path_matches_lloyd():
-    from repro.core import yinyang_compact
+@pytest.mark.parametrize("driver", ["kmeans_api", "engine_fit"])
+def test_compact_path_matches_lloyd(driver):
+    """The engine's ``compact`` backend, through ``KMeans`` and through
+    ``engine.fit``, lands on Lloyd's fixed point with fewer distance
+    evaluations."""
     pts, init, k = _dataset(n=4000, k=24, seed=7)
     r_l = lloyd(pts, init, max_iters=40, tol=1e-5)
-    r_c = yinyang_compact(pts, init, max_iters=40, tol=1e-5)
+    if driver == "kmeans_api":
+        # KMeans(seed=8) seeds with PRNGKey(8), as _dataset(seed=7) does
+        r_c = KMeans(n_clusters=k, seed=8, max_iters=40, tol=1e-5,
+                     engine="compact", tune="off").fit(pts).result_
+    else:
+        r_c = engine.fit(pts, init, max_iters=40, tol=1e-5,
+                         backend="compact", tune="off")
     np.testing.assert_allclose(float(r_l.inertia), float(r_c.inertia),
                                rtol=1e-5)
     agree = (np.asarray(r_l.assignments) ==

@@ -26,7 +26,7 @@ from repro.core.api import KMeans
 from repro.data import make_points
 from repro.obs import (MetricsRegistry, ObsConfig, add_ring_listener,
                        caps_from_ring, compile_count, default_registry,
-                       normalize_obs, provenance, reduce_shard_rings,
+                       normalize_obs, reduce_shard_rings,
                        remove_ring_listener, shard_skew, span,
                        summarize_ring)
 from repro.obs.ring import (COL_EVALS, COL_INERTIA, COL_N_CAND,
@@ -396,14 +396,6 @@ def test_normalize_obs_coercions():
     cfg2 = normalize_obs(reg)
     assert cfg2.resolve_registry() is reg
     assert normalize_obs(cfg2) is cfg2
-
-
-def test_provenance_shape():
-    p = provenance()
-    for key in ("timestamp", "git_sha", "jax_version", "platform",
-                "device_count"):
-        assert key in p
-    json.dumps(p)
 
 
 # -------------------------------------------------------------------------

@@ -126,6 +126,20 @@ def test_resume_across_runs_bit_exact(tmp_path):
     assert sk_b.stats_.replayed_batches == 0   # resumed, nothing replayed
 
 
+def test_checkpointed_fit_bit_exact_with_plain_fit(tmp_path):
+    """Checkpointing is a pure observer: a failure-free fit under
+    ``resilient=True``, saving every 3 batches and at the end, is
+    bit-identical to the plain fit."""
+    stream = _stream(seed=4)
+    sk_u = StreamingKMeans(8, seed=3).fit_stream(stream, epochs=2)
+    sk_c = StreamingKMeans(8, seed=3)
+    sk_c.fit_stream(stream, epochs=2, resilient=True, ckpt_dir=tmp_path,
+                    ckpt_every=3)
+    _assert_stream_state_equal(sk_u, sk_c)
+    assert sk_c.stats_.ckpt_saves >= 3
+    assert sk_c.stats_.restores == 0
+
+
 def test_adopt_centroids_keeps_cached_bounds_valid():
     """Warm handover: adopted centroids enter the ledger as drift, so
     the stream continues on the old bound cache without violating a
