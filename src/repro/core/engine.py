@@ -440,6 +440,22 @@ def move_and_bounds(points, centroids, assignments, ub, lb, groups,
                    bcounts)
 
 
+def cap_old_group(lb, old_group, changed, ub_t):
+    """Yinyang's exactness cap: a point that left centroid b (``changed``)
+    puts b back in its group's non-assigned pool at exact distance
+    ``ub_t``, so the lower bound of b's group (``old_group``) becomes
+    ``min(lb, ub_t)``; every other bound stays. A one-hot select over
+    the (N, G) bounds, so it fuses into the elementwise pass that wrote
+    them. The scatter-min ``lb.at[rows, old_group].min(...)`` it replaces
+    (kept in :func:`repro.core.kmeans._filtered_step`, the reference)
+    goes one row at a time on the TPU: 21 ms a pass at N = 2.46M, G = 5
+    on one TPU v5e. Bit for bit the scatter-min's result.
+    """
+    hit = ((jnp.arange(lb.shape[1], dtype=old_group.dtype)[None, :]
+            == old_group[:, None]) & changed[:, None])
+    return jnp.where(hit, jnp.minimum(lb, ub_t[:, None]), lb)
+
+
 def dense_candidate_pass(points, new_c, assignments, ub_t, lb, groups, need,
                          *, n_groups: int, opt_sq: bool = True,
                          x2=None, c2=None):
@@ -479,10 +495,8 @@ def dense_candidate_pass(points, new_c, assignments, ub_t, lb, groups, need,
                                   num_segments=n_groups).T          # (N, G)
     if opt_sq:
         lb_comp = jnp.sqrt(lb_comp)
-    new_lb = jnp.where(group_need, lb_comp, lb)
-    old_group = groups[assignments]
-    new_lb = new_lb.at[rows, old_group].min(
-        jnp.where(changed, ub_t, jnp.inf))
+    new_lb = cap_old_group(jnp.where(group_need, lb_comp, lb),
+                           groups[assignments], changed, ub_t)
     return new_assign, new_ub, new_lb, pairs
 
 
@@ -640,9 +654,7 @@ def compact_candidate_pass(points, new_c, assignments, ub_t, lb, groups,
     else:
         nas, nub, new_clb, pairs, chg = dense_branch(None)
 
-    old_group = jnp.take(groups, c_as)                        # (cap,)
-    new_clb = new_clb.at[rows, old_group].min(
-        jnp.where(chg, c_ub, jnp.inf))
+    new_clb = cap_old_group(new_clb, jnp.take(groups, c_as), chg, c_ub)
 
     # --- scatter survivors back (invalid slots dropped) --------------
     sidx = jnp.where(valid, idx, n)
@@ -780,8 +792,6 @@ def pallas_candidate_pass(points, new_c, assignments, ub_t, lb, groups,
     """
     from ..kernels import build_group_block_mask, grouped_assign
 
-    n = points.shape[0]
-    rows = jnp.arange(n)
     group_need = need[:, None] & (lb < ub_t[:, None])              # (N, G)
     mask = build_group_block_mask(group_need, tile_n=tile_n)       # (gn, G)
     mem_s = jnp.maximum(members, 0)
@@ -800,10 +810,8 @@ def pallas_candidate_pass(points, new_c, assignments, ub_t, lb, groups,
     # argmin collides with the assignment iff the assignment came from
     # that group, in which case the second-min is the excluded min.
     lb_comp = jnp.sqrt(jnp.where(garg == new_assign[:, None], gmin2, gmin))
-    new_lb = jnp.where(group_need, lb_comp, lb)
-    old_group = groups[assignments]
-    new_lb = new_lb.at[rows, old_group].min(
-        jnp.where(changed, ub_t, jnp.inf))
+    new_lb = cap_old_group(jnp.where(group_need, lb_comp, lb),
+                           groups[assignments], changed, ub_t)
     pairs = jnp.float32(tile_n) * jnp.sum(
         mask.astype(jnp.float32) * gsize[None, :])
     return new_assign, new_ub, new_lb, pairs

@@ -345,3 +345,105 @@ def test_census_shape_onehot_sums_match_plain_lloyd(monkeypatch):
     assert np.all(np.abs(own - best) <= 1e-5 * best)
     np.testing.assert_allclose(np.asarray(km.cluster_centers_),
                                np.asarray(c_ref), rtol=0, atol=1e-5)
+
+
+def _scatter_cap(lb, old_group, changed, ub_t):
+    """The lower-bound cap as the reference step writes it."""
+    rows = jnp.arange(lb.shape[0])
+    return lb.at[rows, old_group].min(jnp.where(changed, ub_t, jnp.inf))
+
+
+@pytest.mark.parametrize("n,g", [(4_096 + 173, 5), (4_096 + 173, 102),
+                                 (8_192, 102)])
+def test_cap_old_group_matches_scatter_min_bits(n, g):
+    """The one-hot select gives the scatter-min's bounds bit for bit:
+    ragged N, changed and unchanged rows, infinite bounds, and caps that
+    bind (bound above ``ub_t``) or not (bound below)."""
+    rng = np.random.default_rng(n + g)
+    lb = rng.uniform(0.0, 4.0, (n, g)).astype(np.float32)
+    lb[rng.random((n, g)) < 0.1] = np.inf
+    ub_t = rng.uniform(0.0, 4.0, n).astype(np.float32)
+    ub_t[:7] = lb[np.arange(7), 0]          # ties with the bound
+    changed = rng.random(n) < 0.3
+    old_group = rng.integers(0, g, n).astype(np.int32)
+    old_group[:7] = 0
+    lb, ub_t, changed, old_group = map(jnp.asarray,
+                                       (lb, ub_t, changed, old_group))
+    want = _scatter_cap(lb, old_group, changed, ub_t)
+    got = jax.jit(engine.cap_old_group)(lb, old_group, changed, ub_t)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+    # the case is not vacuous: some caps bind, on finite and inf bounds
+    bound = np.asarray(got) != np.asarray(lb)
+    assert bound.sum() > n // 20
+    assert np.isinf(np.asarray(lb)[bound]).any()
+
+
+def test_pallas_pass_lower_bounds_match_reference_step(monkeypatch):
+    """One iteration mid-fit, at a ragged N: the interpreted Pallas
+    candidate pass, fed the engine's move of the same state, returns the
+    labels of ``kmeans._filtered_step`` (whose cap is the scatter-min),
+    and its (N, G) lower bounds bit for bit wherever the kernel computed
+    just the groups the point needed. (A live block computes a group for
+    every point of its tile, which may tighten other rows' bounds.) The
+    rows are sorted by the groups they need, so that whole tiles are of
+    one kind, and among them are rows that left a group the pass
+    skipped."""
+    from repro.core.distances import row_norms_sq
+    from repro.core.kmeans import _filtered_step, _init_filter_state
+    from repro.kernels import build_group_block_mask
+    n, d, k, g, tile_n = 4_096 + 173, 8, 50, 5, 256
+    pts = jnp.asarray(make_points(n, d, k, seed=3, spread=1.0)[0])
+    init = kmeans_plusplus(jax.random.PRNGKey(3), pts, k)
+    groups = engine.group_centroids(init, g)
+    members, gsize = engine.build_group_tables(
+        np.asarray(jax.device_get(groups)), g)
+
+    def move(pts, st):
+        return engine.move_and_bounds(
+            pts, st.centroids, st.assignments, st.ub, st.lb, groups, k=k,
+            n_groups=g, x2=row_norms_sq(pts))
+
+    def group_need(mo):
+        return np.asarray(mo.need[:, None] & (mo.lb < mo.ub[:, None]))
+
+    st = _init_filter_state(pts, init, groups, g)
+    for _ in range(2):
+        st = _filtered_step(pts, st, groups, g, k)
+    perm = np.argsort(group_need(move(pts, st)) @ (1 << np.arange(g)),
+                      kind="stable")
+    pts = pts[perm]
+    st = st._replace(assignments=st.assignments[perm], ub=st.ub[perm],
+                     lb=st.lb[perm])
+    x2 = row_norms_sq(pts)
+    ref = _filtered_step(pts, st, groups, g, k, x2=x2)
+    mo = move(pts, st)
+    np.testing.assert_array_equal(np.asarray(mo.centroids),
+                                  np.asarray(ref.centroids))
+
+    def pallas_pass():
+        return engine.pallas_candidate_pass(
+            pts, mo.centroids, st.assignments, mo.ub, mo.lb, groups,
+            members, gsize, mo.need, n_groups=g, tile_n=tile_n,
+            interpret=True, x2=x2, c2=mo.c2)
+
+    new_assign, _, new_lb, _ = pallas_pass()
+    np.testing.assert_array_equal(np.asarray(new_assign),
+                                  np.asarray(ref.assignments))
+    gn = group_need(mo)
+    mask = np.asarray(build_group_block_mask(jnp.asarray(gn),
+                                             tile_n=tile_n))
+    exact = (mask[np.arange(n) // tile_n] == gn).all(axis=1)
+    np.testing.assert_array_equal(np.asarray(new_lb)[exact].view(np.uint32),
+                                  np.asarray(ref.lb)[exact].view(np.uint32))
+    rows = np.arange(n)
+    old = np.asarray(groups)[np.asarray(st.assignments)]
+    binds = (exact & (np.asarray(new_assign) != np.asarray(st.assignments))
+             & ~gn[rows, old]
+             & (np.asarray(mo.lb)[rows, old] > np.asarray(mo.ub)))
+    assert binds.sum() > 0
+    # every row, the pass with the scatter-min in the select's place
+    monkeypatch.setattr(engine, "cap_old_group", _scatter_cap)
+    np.testing.assert_array_equal(
+        np.asarray(new_lb).view(np.uint32),
+        np.asarray(pallas_pass()[2]).view(np.uint32))

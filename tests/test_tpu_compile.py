@@ -14,6 +14,7 @@ cannot be read back without one).
 """
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,21 +108,33 @@ def test_grouped_assign_group_loop_compiles_for_v5e(one_chip, n, d, g, lmax,
             + mem.temp_size_in_bytes) < 16 << 30
 
 
+def _has_scatter(compiled):
+    """Whether the program holds a scatter instruction (the opcode, not
+    the word, which source names in the metadata may carry)."""
+    return re.search(r"\sscatter\(", compiled.as_text()) is not None
+
+
+def _compile_candidate_pass(sharding, n, d, k, g, lmax):
+    """``engine.pallas_candidate_pass`` compiled at (N, D, K, G, Lmax)."""
+    s = _on(sharding)
+    fn = jax.jit(lambda *a: _engine.pallas_candidate_pass(
+        *a[:9], n_groups=g, tile_n=256, interpret=False, x2=a[9],
+        c2=a[10]))
+    return fn.lower(
+        s((n, d), jnp.float32), s((k, d), jnp.float32),
+        s((n,), jnp.int32), s((n,), jnp.float32), s((n, g), jnp.float32),
+        s((k,), jnp.int32), s((g, lmax), jnp.int32), s((g,), jnp.float32),
+        s((n,), jnp.bool_), s((n,), jnp.float32),
+        s((k,), jnp.float32)).compile()
+
+
 def test_pallas_candidate_pass_compiles_for_v5e(one_chip):
     """The engine's pallas candidate pass (mask build, grouped centroid
     gather, kernel, lower-bound refresh) jitted at uci-xlarge."""
     n, d, k, g = _problem("uci-xlarge")
-    s = _on(one_chip)
-    fn = jax.jit(lambda *a: _engine.pallas_candidate_pass(
-        *a[:9], n_groups=g, tile_n=256, interpret=False, x2=a[9],
-        c2=a[10]))
-    compiled = fn.lower(
-        s((n, d), jnp.float32), s((k, d), jnp.float32),
-        s((n,), jnp.int32), s((n,), jnp.float32), s((n, g), jnp.float32),
-        s((k,), jnp.int32), s((g, LMAX), jnp.int32), s((g,), jnp.float32),
-        s((n,), jnp.bool_), s((n,), jnp.float32),
-        s((k,), jnp.float32)).compile()
+    compiled = _compile_candidate_pass(one_chip, n, d, k, g, LMAX)
     assert "tpu_custom_call" in compiled.as_text()
+    assert not _has_scatter(compiled)
     # the (N, G) bounds and the kernel's outputs fit one 16 GB chip
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
@@ -131,22 +144,24 @@ def test_census_candidate_pass_compiles_for_v5e(one_chip):
     N = 2,458,285, 173 past a multiple of the tile (the points, their
     norms and the block mask are padded in every pass), D = 68 off the
     128 lanes, K = 50 in G = 5 groups; arguments, outputs and temporaries
-    within one 16 GB chip."""
-    n, d, k, g = 2_458_285, 68, 50, 5
-    s = _on(one_chip)
-    fn = jax.jit(lambda *a: _engine.pallas_candidate_pass(
-        *a[:9], n_groups=g, tile_n=256, interpret=False, x2=a[9],
-        c2=a[10]))
-    compiled = fn.lower(
-        s((n, d), jnp.float32), s((k, d), jnp.float32),
-        s((n,), jnp.int32), s((n,), jnp.float32), s((n, g), jnp.float32),
-        s((k,), jnp.int32), s((g, LMAX), jnp.int32), s((g,), jnp.float32),
-        s((n,), jnp.bool_), s((n,), jnp.float32),
-        s((k,), jnp.float32)).compile()
+    within one 16 GB chip; the lower-bound cap a select, no scatter."""
+    compiled = _compile_candidate_pass(one_chip, 2_458_285, 68, 50, 5, LMAX)
     assert "tpu_custom_call" in compiled.as_text()
+    assert not _has_scatter(compiled)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16 << 30
+
+
+def test_sift_candidate_pass_has_no_scatter_for_v5e(one_chip):
+    """The engine's pallas candidate pass at sift128-ivf1024's shape
+    (N = 262,144, D = 128, K = 1,024 in G = 102 groups of up to 72): the
+    lower-bound cap fuses into the (N, G) select, no row-serial scatter
+    is left in the program."""
+    compiled = _compile_candidate_pass(one_chip, 262_144, 128, 1_024, 102,
+                                       72)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert not _has_scatter(compiled)
 
 
 @pytest.mark.parametrize("n,d,k", [
